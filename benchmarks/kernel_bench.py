@@ -85,10 +85,13 @@ def _paged_case(rng, ctx: int, bs: int = 64):
         return jnp.einsum("bkgqs,bskd->bqkgd", w, v).reshape(S, 1, H, D)
 
     args = (q, k_pool, v_pool, table, positions)
-    us_fused = time_fn(lambda: fused(*args), iters=3, warmup=1)
+    # the kernel reads the pools as stored: one position's heads side by side
+    fargs = (q, k_pool.reshape(nb, bs, KVH * D), v_pool.reshape(nb, bs, KVH * D),
+             table, positions)
+    us_fused = time_fn(lambda: fused(*fargs), iters=3, warmup=1)
     us_gather = time_fn(lambda: gather(*args), iters=3, warmup=1)
     exact = bool(jnp.array_equal(
-        fused(*args).astype(jnp.float32), gather(*args).astype(jnp.float32)))
+        fused(*fargs).astype(jnp.float32), gather(*args).astype(jnp.float32)))
     return us_fused, us_gather, exact
 
 

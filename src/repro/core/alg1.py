@@ -50,8 +50,11 @@ def saturating_sum(x, saturation: int, axis: int = -1):
 
     Equals ``min(sum(x), saturation)`` exactly (proof: by induction each subtree
     yields min(subtree_sum, sat); a clipped parent of exact children is exact
-    below sat and pinned at sat above it). Mirrors the 2D AP's log2-stage
-    row-pair reduction, with the accumulator saturating at the Table-I width.
+    below sat and pinned at sat above it — for ANY binary tree). Mirrors the
+    2D AP's log2-stage row-pair reduction, with the accumulator saturating at
+    the Table-I width. Each level adds the two contiguous halves: a strided
+    ``x[0::2] + x[1::2]`` pairing gives the same codes but does not lower in a
+    TPU kernel (Mosaic has no lane-strided slice).
     ``saturation`` must be <= 2^30 - 1 so a pairwise add cannot overflow int32.
     """
     if saturation > 2**30 - 1:
@@ -65,7 +68,8 @@ def saturating_sum(x, saturation: int, axis: int = -1):
         x = jnp.pad(x, pad)
     sat = jnp.int32(saturation)
     while x.shape[-1] > 1:
-        x = jnp.minimum(x[..., 0::2] + x[..., 1::2], sat)
+        h = x.shape[-1] // 2
+        x = jnp.minimum(x[..., :h] + x[..., h:], sat)
     # final clip covers the single-element case (contract: min(sum, sat))
     return jnp.minimum(x[..., 0], sat)
 

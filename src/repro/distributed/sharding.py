@@ -47,8 +47,9 @@ DEFAULT_RULES: dict = {
     # hidden before down): "model" here = the layout the producing einsum
     # already emits, so the constraint is a no-op; the serving rules remap it
     # to None, all-gathering the operand so the contraction runs in full on
-    # every device (deterministic, bitwise vs single-device) instead of as
-    # partial-sum + psum (order-dependent rounding)
+    # every device (deterministic; bitwise vs single-device on the CPU, within
+    # bf16 rounding on a TPU) instead of as partial-sum + psum
+    # (order-dependent rounding)
     "tp_collect": "model",
     "head_dim": None,
     "state": None,
@@ -137,8 +138,7 @@ def logical_constraint(x, logical_axes: Sequence[Optional[str]],
 
     No-op when ``rules`` is None (single-device tests) or no mesh is
     resolvable. Accepts an explicit concrete mesh (preferred: works under any
-    context) or falls back to the ambient mesh (jax.set_mesh on new JAX, the
-    ``with mesh:`` context on older releases).
+    context) or falls back to the ambient mesh set by ``jax.set_mesh``.
     """
     if rules is None:
         return x
@@ -149,39 +149,24 @@ def logical_constraint(x, logical_axes: Sequence[Optional[str]],
     if isinstance(mesh, Mesh):  # concrete mesh: NamedSharding works anywhere
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(mesh, rules.spec(logical_axes, mesh)))
-    # abstract mesh (jax >= 0.7 jax.set_mesh): bare PartitionSpec form
+    # abstract mesh (jax.set_mesh): bare PartitionSpec form
     return jax.lax.with_sharding_constraint(x, rules.spec(logical_axes, mesh))
 
 
 def get_abstract_mesh():
-    """The ambient mesh, if any: ``jax.set_mesh``'s abstract mesh on new JAX,
-    the ``with mesh:`` thread-resource mesh on older releases."""
-    getter = getattr(jax.sharding, "get_abstract_mesh", None)
-    if getter is not None:
-        m = getter()
-        if m is None or getattr(m, "empty", False):
-            return None
-        return m
-    from jax.interpreters import pxla  # pre-0.7 fallback
-
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
+    """The ambient mesh set by ``jax.set_mesh``, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m is None or m.empty else m
 
 
-def make_mesh(shape, axis_names):
-    """jax.make_mesh, with Auto axis types where the installed JAX has them
-    (jax >= 0.7; quiet under 0.8/0.9) and the plain signature otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axis_names)
+def make_mesh(shape, axis_names, devices=None):
+    """``jax.make_mesh`` with Auto axis types on every axis (over
+    ``devices`` when given, else every visible device)."""
     return jax.make_mesh(
-        shape, axis_names, axis_types=(axis_type.Auto,) * len(axis_names))
+        shape, axis_names, devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def use_mesh(mesh: Mesh):
-    """Version-portable ambient-mesh context manager: ``jax.set_mesh`` where
-    available, else the Mesh object itself (a context manager pre-0.7)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Ambient-mesh context manager (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)

@@ -7,6 +7,7 @@ from functools import partial
 import jax
 
 from repro.core.precision import PrecisionConfig
+from repro.kernels import resolve_interpret
 from repro.kernels.int_attention.kernel import int_attention_kernel
 
 
@@ -26,7 +27,7 @@ def int_attention_pallas(q, k, v, cfg: PrecisionConfig = PrecisionConfig(),
     """q: [B, H, Sq, D]; k, v: [B, KV, Skv, D] -> [B, H, Sq, D] float32."""
     b, h, sq, d = q.shape
     kv, skv = k.shape[1], k.shape[2]
-    interpret = (jax.default_backend() != "tpu") if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     blk_q = _auto_blk_q(skv) if blk_q is None else blk_q
     out = int_attention_kernel(
         q.reshape(b * h, sq, d), k.reshape(b * kv, skv, d),

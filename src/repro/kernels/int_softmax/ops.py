@@ -8,12 +8,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.precision import PrecisionConfig
+from repro.kernels import resolve_interpret
 from repro.kernels.int_softmax.kernel import int_softmax_kernel
-
-
-def _interpret_default() -> bool:
-    # interpret mode on CPU (this container); compiled path on real TPUs
-    return jax.default_backend() != "tpu"
 
 
 @partial(jax.jit, static_argnames=("cfg", "axis", "row_block", "interpret"))
@@ -24,7 +20,7 @@ def int_softmax_pallas(x, cfg: PrecisionConfig = PrecisionConfig(), mask=None,
     Accepts arbitrary leading dims; softmax over the last axis."""
     if axis not in (-1, x.ndim - 1):
         raise ValueError("int_softmax_pallas computes over the last axis")
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     shape = x.shape
     x2 = x.reshape(-1, shape[-1])
     m2 = None

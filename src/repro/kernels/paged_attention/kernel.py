@@ -8,18 +8,28 @@ not to the tokens a slot has actually written. These kernels instead stream
 K/V **pages** straight from the global pool, one VMEM residency per
 (slot, kv-head) program:
 
-  grid = (S, KV, n_logical / PPS)         dense / GQA
-  grid = (S,     n_logical / PPS)         MLA (latent cache is head-shared)
+  grid = (S, KV, n_pad / PPS)             dense / GQA
+  grid = (S,     n_pad / PPS)             MLA (latent cache is head-shared)
 
 with the per-slot block table and the per-row query positions passed as
 **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``): the k/v
 ``BlockSpec`` index maps read ``table[s, page]`` to pick the physical pool
 block each grid step fetches, so the data path never touches a dense
-gathered intermediate. Per page the kernel computes the QK^T score slice
-into a ``[ROWS, L]`` VMEM scratch (and stages the V page into an ``[L, Dv]``
-scratch); at the last page of a slot it applies the shared Alg.-1 integer
-softmax (``core/alg1.py``) over the FULL rows and the weighted PV sum in
-the same residency.
+gathered intermediate. The dense pools are stored ``[NB, BS, KV * D]``
+(the heads of one position side by side), so head ``h`` of a page is the
+``[BS, D]`` block at lane offset ``h * D`` — a tile-aligned fetch of
+exactly that head's bytes. Each grid step stages its PPS pages into a
+``[PPS * BS, D]`` K slab and computes one ``[ROWS, PPS * BS]`` score slab
+into a ``[ROWS, L]`` VMEM scratch (and stages the V pages into an
+``[L, Dv]`` scratch); at the last step of a slot it applies the shared
+Alg.-1 integer softmax (``core/alg1.py``) over the FULL rows and the
+weighted PV sum in the same residency.
+
+TPU tiling: a score slab is stored at a dynamic lane offset, which Mosaic
+accepts only at a multiple of 128 lanes, so PPS * BS is a multiple of 128
+(``aligned_pages``) and the wrapper pads the block table with sentinel
+pages up to a whole number of steps. Padded columns lie past the real
+cache length and are masked out, so they add nothing to a row.
 
 DESIGN NOTE — why full rows, not online rescaling: flash-style softmax
 accumulates ``exp(x - m_running)`` and rescales the partial sums when the
@@ -27,21 +37,23 @@ running max moves. That identity (``exp(a - b) = exp(a) / exp(b)``) does NOT
 hold for the paper's integer exponential: Alg. 1 quantizes ``x - max(x)``
 onto an M-bit grid and evaluates a fixed-point LUT polynomial, so
 re-quantizing against a shifted max lands on DIFFERENT grid points and the
-"rescaled" integer probabilities diverge from the one-shot ones (see
-``kernels/int_attention/kernel.py`` and DESIGN.md, which pin the same
-constraint for the prefill kernel). The kernel therefore keeps whole score
-rows resident — cheap at decode, where ROWS = T * G is tiny — and stays
-bit-identical to the gather reference instead of approximately close.
+"rescaled" integer probabilities diverge from the one-shot ones (the prefill
+kernel, ``kernels/int_attention/kernel.py``, keeps whole rows for the same
+reason). The kernel therefore keeps whole score rows resident — cheap at
+decode, where ROWS = T * G is tiny — and stays bit-identical to the gather
+reference instead of approximately close.
 
-Bit-exactness contract (vs gather + the ``int_jax`` backend):
+Bit-exactness contract (vs gather + the ``int_jax`` backend, interpret
+mode; compiled on a TPU v5e, decode logits agree with the gather path
+only within a few bf16 ulps — ``chip_smoke.py`` measures the drift):
 
-  * each page's score slice is ``dot_general(q, k_page)`` with f32
+  * each step's score slab is ``dot_general(q, k_slab)`` with f32
     accumulation, rounded through the compute dtype and cast to the scores
     dtype EXPLICITLY — ``jnp.einsum`` on bf16 operands rounds its f32
     accumulator to bf16 before the reference's ``.astype(float32)``, and
     matching that rounding is what makes the kernel's scores equal the
     reference's bit for bit (QK^T columns depend only on their own K rows,
-    so per-page slices assemble the full-row dot exactly);
+    so per-step slabs assemble the full-row dot exactly);
   * the MLA score is the SUM of two dots (latent + rope); XLA rounds each
     einsum to bf16 and performs the add in f32 ("semi" semantics) — the
     kernel reproduces that explicitly instead of letting one fused dot
@@ -51,16 +63,20 @@ Bit-exactness contract (vs gather + the ``int_jax`` backend):
     contract;
   * the int8 KV dequant (``kv_quant``) is fused into the page load:
     ``(codes.astype(f32) * scale).astype(compute)`` is elementwise, so
-    dequantizing per page equals dequantizing the gathered whole.
+    dequantizing per page equals dequantizing the gathered whole. The
+    ``[BS, KV]`` scale page is fetched whole and head ``h``'s column is
+    selected by a masked lane sum, which is exact (one term, the rest 0).
 
-VMEM per program: scores ROWS*L*4 + V scratch L*Dv + PPS page tiles;
-``ops.choose_tiles`` picks PPS against the roofline VMEM model
-(``launch/roofline.py``) and fails loudly when no tile fits.
+VMEM per program: ``launch/roofline.paged_tile_vmem_bytes``; ``ops``
+picks PPS against it and passes the same budget to the compiler as the
+kernel's VMEM limit, so a tile the model accepts is one the compiler
+accepts.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -70,15 +86,30 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.alg1 import int_softmax_block
 from repro.core.precision import PrecisionConfig
 
+LANES = 128
+
+
+def aligned_pages(block_size: int) -> int:
+    """Fewest pages per grid step whose score slab (``pps * block_size``
+    columns) is a whole number of 128-lane tiles."""
+    return LANES // math.gcd(block_size, LANES)
+
 
 def _page_tile(tile, ent, nb, scale=None, compute_dtype=None):
-    """One [BS, D] page tile: dequantized when a scale vector rides along,
-    zeroed when the table entry ``ent`` is a sentinel (outside [0, nb))."""
+    """One [BS, D] page tile: dequantized when a [BS, 1] scale column rides
+    along, zeroed when the table entry ``ent`` is a sentinel (outside
+    [0, nb))."""
     if scale is not None:
-        tile = (tile.astype(jnp.float32)
-                * scale[..., None]).astype(compute_dtype)
+        tile = (tile.astype(jnp.float32) * scale).astype(compute_dtype)
     live = (ent >= 0) & (ent < nb)
     return jnp.where(live, tile, jnp.zeros_like(tile))
+
+
+def _head_column(scales, h):
+    """Column ``h`` of a [BS, KV] scale page as [BS, 1] — a masked lane sum,
+    exact because every other term is zero."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 1)
+    return jnp.sum(jnp.where(lane == h, scales, 0.0), axis=1, keepdims=True)
 
 
 def _rounded_dot(a, b, compute_dtype):
@@ -89,13 +120,19 @@ def _rounded_dot(a, b, compute_dtype):
     return out.astype(compute_dtype)
 
 
-def _row_mask(pos_row, group, window, shape):
-    """[ROWS, L] validity: row t*group+g attends kv positions <= pos_row[t]
-    (within the trailing window when set) — ``valid_upto``/``verify_mask``
-    semantics, shared by decode (T=1) and speculative verify (T=K+1)."""
-    qpos = jnp.repeat(pos_row, group)[:, None].astype(jnp.int32)
+def _row_mask(pos_ref, s, group, window, shape, length):
+    """[ROWS, L] validity: row t*group+g attends kv positions <= pos[s, t]
+    (within the trailing window when set) and below the real cache
+    ``length`` (the padded tail never counts) — ``valid_upto``/
+    ``verify_mask`` semantics, shared by decode (T=1) and speculative
+    verify (T=K+1). Positions are read one scalar at a time: a TPU kernel
+    loads only scalars from SMEM, where the prefetched positions live."""
+    row_t = jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0) // group
+    qpos = jnp.zeros((shape[0], 1), jnp.int32)
+    for t in range(pos_ref.shape[1]):
+        qpos = jnp.where(row_t == t, pos_ref[s, t], qpos)
     kpos = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    mask = kpos <= qpos
+    mask = (kpos <= qpos) & (kpos < length)
     if window:
         mask &= kpos > qpos - window
     return mask
@@ -109,35 +146,48 @@ def _pv(probs, v_scr, compute_dtype):
     return out.astype(compute_dtype)
 
 
+def _slab_cols(pp, width):
+    """The 128-aligned lane window of grid step ``pp``'s score slab."""
+    return pl.ds(pl.multiple_of(pp * width, LANES), width)
+
+
+def _compiler_params(vmem_limit):
+    return (None if vmem_limit is None
+            else pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit)))
+
+
 # --------------------------------------------------------------- dense / GQA
 
 
 def _dense_kernel(table_ref, pos_ref, q_ref, *refs, cfg: PrecisionConfig,
                   scale: float, window: int, group: int, pps: int, bs: int,
-                  nb: int, quant: bool, compute_dtype, scores_dtype):
+                  nb: int, length: int, quant: bool, compute_dtype,
+                  scores_dtype):
     k_refs = refs[:pps]
     v_refs = refs[pps:2 * pps]
     ks_refs = refs[2 * pps:3 * pps] if quant else (None,) * pps
     vs_refs = refs[3 * pps:4 * pps] if quant else (None,) * pps
     nin = pps * (4 if quant else 2)
-    o_ref, scores, v_scr = refs[nin], refs[nin + 1], refs[nin + 2]
+    o_ref, scores, v_scr, k_slab = refs[nin:nin + 4]
 
-    s, pp = pl.program_id(0), pl.program_id(2)
-    qt = q_ref[0, 0]                                   # [ROWS, D]
+    s, h, pp = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     for j in range(pps):
         page = pp * pps + j
         ent = table_ref[s, page]
-        kt = _page_tile(k_refs[j][0, :, 0, :], ent, nb,
-                        ks_refs[j][0, :, 0] if quant else None, compute_dtype)
-        vt = _page_tile(v_refs[j][0, :, 0, :], ent, nb,
-                        vs_refs[j][0, :, 0] if quant else None, compute_dtype)
-        st = _rounded_dot(qt, kt, compute_dtype).astype(scores_dtype) * scale
-        scores[:, pl.ds(page * bs, bs)] = st.astype(jnp.float32)
-        v_scr[pl.ds(page * bs, bs), :] = vt
+        ks = _head_column(ks_refs[j][0], h) if quant else None
+        vs = _head_column(vs_refs[j][0], h) if quant else None
+        k_slab[pl.ds(j * bs, bs), :] = _page_tile(k_refs[j][0], ent, nb, ks,
+                                                  compute_dtype)
+        v_scr[pl.ds(page * bs, bs), :] = _page_tile(v_refs[j][0], ent, nb,
+                                                    vs, compute_dtype)
+    width = pps * bs
+    st = _rounded_dot(q_ref[0, 0], k_slab[...],
+                      compute_dtype).astype(scores_dtype) * scale
+    scores[:, _slab_cols(pp, width)] = st.astype(jnp.float32)
 
     @pl.when(pp == pl.num_programs(2) - 1)
     def _():
-        mask = _row_mask(pos_ref[s], group, window, scores.shape)
+        mask = _row_mask(pos_ref, s, group, window, scores.shape, length)
         probs = int_softmax_block(scores[...].astype(scores_dtype), mask, cfg)
         o_ref[0, 0] = _pv(probs, v_scr[...], compute_dtype)
 
@@ -145,51 +195,57 @@ def _dense_kernel(table_ref, pos_ref, q_ref, *refs, cfg: PrecisionConfig,
 def paged_attention_dense(q, k_pool, v_pool, table, positions,
                           cfg: PrecisionConfig, *, scale: float,
                           window: int = 0, k_scale=None, v_scale=None,
-                          scores_dtype=jnp.float32, pps: int = 1,
+                          scores_dtype=jnp.float32, pps: int = 8,
+                          length=None, vmem_limit=None,
                           interpret: bool = True):
     """Fused paged decode attention, dense/GQA layout.
 
     q          [S, KV, ROWS, D]   ROWS = T * group, row order t*group+g
-    k/v_pool   [NB, BS, KV, D]    global block pools (int8 codes when the
-                                  matching ``*_scale`` [NB, BS, KV] rides)
+    k/v_pool   [NB, BS, KV * D]   global block pools, heads side by side
+                                  (int8 codes when the matching
+                                  ``*_scale`` [NB, BS, KV] rides)
     table      [S, NLOG] int32    per-slot block table; sentinel = any
-                                  entry outside [0, NB)
+                                  entry outside [0, NB); NLOG % pps == 0
     positions  [S, T]  int32      per-query absolute positions
+    length     real cache length (default NLOG * BS); columns past it are
+               padding and masked out
     -> [S, KV, ROWS, Dv] in the compute dtype (q's dtype).
     """
     s_, kv, rows, d = q.shape
     nb, bs = k_pool.shape[:2]
     nlog = table.shape[1]
     t = positions.shape[1]
-    dv = v_pool.shape[-1]
+    dv = v_pool.shape[-1] // kv
+    assert k_pool.shape[-1] == kv * d, (k_pool.shape, kv, d)
     assert rows % t == 0, (rows, t)
     assert nlog % pps == 0, (nlog, pps)
     group = rows // t
     quant = k_scale is not None
     compute_dtype = q.dtype
+    length = nlog * bs if length is None else length
     # the scratch is f32 regardless of scores_dtype: up-casting a rounded
-    # scores slice to f32 is exact, and the final-page softmax re-rounds the
+    # scores slice to f32 is exact, and the final-step softmax re-rounds the
     # whole block through scores_dtype, which is idempotent
     l_full = nlog * bs
 
     def kv_index(j):
         def idx(s, h, pp, table_ref, pos_ref):
-            return (jnp.clip(table_ref[s, pp * pps + j], 0, nb - 1), 0, h, 0)
+            return (jnp.clip(table_ref[s, pp * pps + j], 0, nb - 1), 0, h)
         return idx
 
     def sc_index(j):
         def idx(s, h, pp, table_ref, pos_ref):
-            return (jnp.clip(table_ref[s, pp * pps + j], 0, nb - 1), 0, h)
+            return (jnp.clip(table_ref[s, pp * pps + j], 0, nb - 1), 0, 0)
         return idx
 
     in_specs = [pl.BlockSpec((1, 1, rows, d),
                              lambda s, h, pp, *_: (s, h, 0, 0))]
-    in_specs += [pl.BlockSpec((1, bs, 1, d), kv_index(j)) for j in range(pps)]
-    in_specs += [pl.BlockSpec((1, bs, 1, dv), kv_index(j)) for j in range(pps)]
+    in_specs += [pl.BlockSpec((1, bs, d), kv_index(j)) for j in range(pps)]
+    in_specs += [pl.BlockSpec((1, bs, dv), kv_index(j)) for j in range(pps)]
     operands = [q] + [k_pool] * pps + [v_pool] * pps
     if quant:
-        in_specs += [pl.BlockSpec((1, bs, 1), sc_index(j)) for j in range(pps)]
-        in_specs += [pl.BlockSpec((1, bs, 1), sc_index(j)) for j in range(pps)]
+        in_specs += [pl.BlockSpec((1, bs, kv), sc_index(j))
+                     for j in range(pps)] * 2
         operands += [k_scale] * pps + [v_scale] * pps
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -199,16 +255,19 @@ def paged_attention_dense(q, k_pool, v_pool, table, positions,
         out_specs=pl.BlockSpec((1, 1, rows, dv),
                                lambda s, h, pp, *_: (s, h, 0, 0)),
         scratch_shapes=[pltpu.VMEM((rows, l_full), jnp.float32),
-                        pltpu.VMEM((l_full, dv), compute_dtype)])
+                        pltpu.VMEM((l_full, dv), compute_dtype),
+                        pltpu.VMEM((pps * bs, d), compute_dtype)])
     kernel = functools.partial(
         _dense_kernel, cfg=cfg, scale=scale, window=window, group=group,
-        pps=pps, bs=bs, nb=nb, quant=quant, compute_dtype=compute_dtype,
-        scores_dtype=scores_dtype)
+        pps=pps, bs=bs, nb=nb, length=length, quant=quant,
+        compute_dtype=compute_dtype, scores_dtype=scores_dtype)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((s_, kv, rows, dv), compute_dtype),
         grid_spec=grid_spec,
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
+        name="paged_decode_dense",
     )(table, positions, *operands)
 
 
@@ -217,43 +276,46 @@ def paged_attention_dense(q, k_pool, v_pool, table, positions,
 
 def _mla_kernel(table_ref, pos_ref, ql_ref, qr_ref, *refs,
                 cfg: PrecisionConfig, scale: float, heads: int, pps: int,
-                bs: int, nb: int, compute_dtype):
+                bs: int, nb: int, length: int, compute_dtype):
     c_refs = refs[:pps]
     kr_refs = refs[pps:2 * pps]
-    o_ref, scores, c_scr = refs[2 * pps], refs[2 * pps + 1], refs[2 * pps + 2]
+    o_ref, scores, c_scr, c_slab, kr_slab = refs[2 * pps:2 * pps + 5]
 
     s, pp = pl.program_id(0), pl.program_id(1)
-    ql = ql_ref[0]                                     # [ROWS, R]
-    qr = qr_ref[0]                                     # [ROWS, DR]
     for j in range(pps):
         page = pp * pps + j
         ent = table_ref[s, page]
         ct = _page_tile(c_refs[j][0], ent, nb)         # [BS, R]
-        krt = _page_tile(kr_refs[j][0], ent, nb)       # [BS, DR]
-        # "semi" sum semantics: each dot f32-accumulated then rounded to the
-        # compute dtype, the ADD performed in f32 — exactly how XLA lowers
-        # einsum(latent) + einsum(rope) on bf16 operands
-        s1 = _rounded_dot(ql, ct, compute_dtype).astype(jnp.float32)
-        s2 = _rounded_dot(qr, krt, compute_dtype).astype(jnp.float32)
-        scores[:, pl.ds(page * bs, bs)] = (s1 + s2) * scale
+        c_slab[pl.ds(j * bs, bs), :] = ct
+        kr_slab[pl.ds(j * bs, bs), :] = _page_tile(kr_refs[j][0], ent, nb)
         c_scr[pl.ds(page * bs, bs), :] = ct
+    # "semi" sum semantics: each dot f32-accumulated then rounded to the
+    # compute dtype, the ADD performed in f32 — exactly how XLA lowers
+    # einsum(latent) + einsum(rope) on bf16 operands
+    s1 = _rounded_dot(ql_ref[0], c_slab[...], compute_dtype)
+    s2 = _rounded_dot(qr_ref[0], kr_slab[...], compute_dtype)
+    st = (s1.astype(jnp.float32) + s2.astype(jnp.float32)) * scale
+    scores[:, _slab_cols(pp, pps * bs)] = st
 
     @pl.when(pp == pl.num_programs(1) - 1)
     def _():
-        mask = _row_mask(pos_ref[s], heads, 0, scores.shape)
+        mask = _row_mask(pos_ref, s, heads, 0, scores.shape, length)
         probs = int_softmax_block(scores[...], mask, cfg)
         o_ref[0] = _pv(probs, c_scr[...], compute_dtype)
 
 
 def paged_attention_mla(q_lat, q_rope, c_pool, kr_pool, table, positions,
-                        cfg: PrecisionConfig, *, scale: float, pps: int = 1,
+                        cfg: PrecisionConfig, *, scale: float, pps: int = 8,
+                        length=None, vmem_limit=None,
                         interpret: bool = True):
     """Fused paged absorbed-MLA decode attention.
 
     q_lat      [S, ROWS, R]    absorbed queries, row order t*H + h
     q_rope     [S, ROWS, DR]   rope queries, same row order
     c_pool     [NB, BS, R]     latent pool; kr_pool [NB, BS, DR] rope keys
-    table      [S, NLOG] int32; positions [S, T] int32
+    table      [S, NLOG] int32 (NLOG % pps == 0); positions [S, T] int32
+    length     real cache length (default NLOG * BS); columns past it are
+               padding and masked out
     -> o_lat [S, ROWS, R] in the compute dtype (the ``W_uv`` up-projection
     and output projection stay outside, shared with the reference path).
     """
@@ -284,14 +346,19 @@ def paged_attention_mla(q_lat, q_rope, c_pool, kr_pool, table, positions,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, rows, r), lambda s, pp, *_: (s, 0, 0)),
         scratch_shapes=[pltpu.VMEM((rows, l_full), jnp.float32),
-                        pltpu.VMEM((l_full, r), compute_dtype)])
+                        pltpu.VMEM((l_full, r), compute_dtype),
+                        pltpu.VMEM((pps * bs, r), compute_dtype),
+                        pltpu.VMEM((pps * bs, dr), compute_dtype)])
     kernel = functools.partial(
         _mla_kernel, cfg=cfg, scale=scale, heads=heads, pps=pps, bs=bs,
-        nb=nb, compute_dtype=compute_dtype)
+        nb=nb, length=l_full if length is None else length,
+        compute_dtype=compute_dtype)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((s_, rows, r), compute_dtype),
         grid_spec=grid_spec,
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
+        name="paged_decode_mla",
     )(table, positions, q_lat, q_rope,
       *([c_pool] * pps), *([kr_pool] * pps))

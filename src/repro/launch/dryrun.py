@@ -40,8 +40,6 @@ def _compile_cell(arch, shape, mesh, **kw):
 
 def _costs_of(compiled):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax < 0.7 returns [dict]
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     coll = rl.collective_bytes(hlo)
     coll_total = sum(v for k, v in coll.items() if not k.startswith("_"))

@@ -2,8 +2,8 @@
 
 A function (not a module-level constant) so importing this module never
 touches jax device state — the dry-run must set XLA_FLAGS before first init.
-Mesh construction goes through ``distributed.sharding.make_mesh``, which
-version-gates the ``AxisType`` kwarg (absent on jax < 0.7).
+Mesh construction goes through ``distributed.sharding.make_mesh`` (every
+axis ``AxisType.Auto``).
 """
 
 from __future__ import annotations
@@ -22,20 +22,21 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_host_mesh():
-    """Whatever this host has (tests / examples): (n, 1) data x model."""
-    n = len(jax.devices())
-    return make_mesh((n, 1), ("data", "model"))
+def make_host_mesh(devices=None):
+    """(n, 1) data x model over ``devices`` (default: every device this
+    host has — tests / examples / training)."""
+    devs = list(jax.devices() if devices is None else devices)
+    return make_mesh((len(devs), 1), ("data", "model"), devices=devs)
 
 
-def make_serving_mesh(shards=None, devices=None):
+def make_serving_mesh(shards: int, devices=None):
     """1-D ("model",) mesh over the first ``shards`` devices — the mesh
     ``Engine.serve(mesh=...)`` shards attention heads and the paged block
-    pool across. ``shards=None`` takes every visible device. Raises (rather
+    pool across. Raises (rather
     than letting XLA fail on placement) when the host has too few devices,
     with the simulated-device recipe CI uses."""
     devs = list(jax.devices() if devices is None else devices)
-    n = len(devs) if shards is None else int(shards)
+    n = int(shards)
     if n < 1:
         raise ValueError(f"shards must be >= 1, got {n}")
     if n > len(devs):

@@ -110,30 +110,61 @@ def mfu_like(model_flops_global: float, flops_pd: float, n_chips: int) -> float:
 # --------------------------------------------------------------------------
 # Paged-decode attention operator (the fused block-table kernel)
 
-VMEM_BYTES = 128 * 2 ** 20   # v5e VMEM per core; the kernel's tile budget
+# The scoped VMEM limit the paged-decode kernels are compiled with
+# (``pltpu.CompilerParams(vmem_limit_bytes=...)``) and the budget
+# ``kernels/paged_attention/ops.choose_tiles`` fits tiles into: half of a
+# v5e core's 128 MiB physical VMEM, the rest left to XLA's own fusions.
+# Without an explicit limit Mosaic enforces its default scope (16 MiB).
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+
+def _rup(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
+    """VMEM bytes of a [rows, cols] array in native (sublane, 128) tiles:
+    8 sublanes of 32-bit words, packed 2x for 16-bit and 4x for 8-bit."""
+    return _rup(rows, 8 * (4 // itemsize)) * _rup(cols, 128) * itemsize
 
 
 def paged_tile_vmem_bytes(rows: int, l_full: int, block_size: int,
                           d_head: int, dv_head: int, pps: int,
                           compute_bytes: int = 2, quant: bool = False) -> int:
-    """VMEM resident per (slot, head) program of the paged-decode kernel.
+    """VMEM per (slot, head) program of the paged-decode kernel, counted
+    the way Mosaic lays it out (every array padded to native tiles).
 
-    scores scratch  rows * l_full * 4            (f32, full rows — no online
+    scores scratch  [rows, l_full] f32           (full rows — no online
                                                   rescaling, see kernel docs)
-    V scratch       l_full * dv_head * compute_bytes
-    page tiles      pps * block_size * (d_head + dv_head) * elt
-                    (+ 2 * pps * block_size * 4 scale vectors when quant)
-    q block         rows * d_head * compute_bytes
-    out block       rows * dv_head * compute_bytes
+    V scratch       [l_full, dv_head] compute dtype
+    K slab          [pps * block_size, d_head + dv_head] compute dtype
+                    (dense stages [.., d_head]; MLA stages both latent and
+                    rope slabs, so count both widths)
+    page tiles      2 buffers x pps x ([BS, d_head] + [BS, dv_head]) at the
+                    pool dtype (+ two [BS, KV] f32 scale pages when quant)
+    q / out blocks  2 buffers x [rows, d_head] / [rows, dv_head]
+    softmax         the Alg.-1 body's live full-row temporaries at the last
+                    step: five 32-bit [rows, l_full] arrays and the
+                    compute-dtype probabilities
+
+    Checked against the v5e compiler (``tests/test_tpu_compile.py``): an
+    upper bound on the scoped VMEM Mosaic allocates, within 4-25% of it at
+    the shapes serving uses (1 to 64 rows, 4k to 32k columns).
     """
     elt = 1 if quant else compute_bytes
-    tiles = pps * block_size * (d_head + dv_head) * elt
+    scratch = (_tile_bytes(rows, l_full, 4)
+               + _tile_bytes(l_full, dv_head, compute_bytes)
+               + _tile_bytes(pps * block_size, d_head, compute_bytes)
+               + _tile_bytes(pps * block_size, dv_head, compute_bytes))
+    tiles = pps * (_tile_bytes(block_size, d_head, elt)
+                   + _tile_bytes(block_size, dv_head, elt))
     if quant:
-        tiles += 2 * pps * block_size * 4
-    return (rows * l_full * 4
-            + l_full * dv_head * compute_bytes
-            + tiles
-            + rows * (d_head + dv_head) * compute_bytes)
+        tiles += 2 * pps * _tile_bytes(block_size, 1, 4)
+    blocks = (_tile_bytes(rows, d_head, compute_bytes)
+              + _tile_bytes(rows, dv_head, compute_bytes))
+    softmax = (5 * _tile_bytes(rows, l_full, 4)
+               + _tile_bytes(rows, l_full, compute_bytes))
+    return scratch + 2 * (tiles + blocks) + softmax
 
 
 def paged_decode_operator(slots: int, kv_heads: int, rows: int, d_head: int,

@@ -1,5 +1,5 @@
-"""Serving launcher: restore (or briefly train) a model, then run batched
-generation through the engine with any registered softmax backend (FP
+"""Serving launcher: restore (or briefly train, or seed) a model, then run
+batched generation through the engine with any registered softmax backend (FP
 baselines, SoftmAP integer paths, the Pallas kernel, or the functional AP
 simulator), reporting the per-request AP softmax cost for metered backends.
 
@@ -14,6 +14,9 @@ caching (``Engine.serve``), with per-request latency and attributed AP cost.
         --softmax int --max-new 32 --sampler top_p --top-p 0.9
     PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --smoke \
         --softmax int --continuous --requests 16 --slots 4
+    # published widths (no --smoke), seeded random params, on one chip
+    PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b \
+        --softmax int --continuous --paged --kernel pallas --prompt-len 128
 """
 
 from __future__ import annotations
@@ -32,15 +35,19 @@ from repro.core.precision import PrecisionConfig
 from repro.core.softmax_variants import SoftmaxSpec
 from repro.data.synthetic import SyntheticCorpus
 from repro.distributed.sharding import ShardingRules
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import Model
 from repro.serving.engine import Engine
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama2-7b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the reduced smoke config of --arch "
+                         "(d_model 128, vocab 512) instead of its published "
+                         "widths")
     # registered-names validation at argparse time: a typo'd --softmax or
     # --serve-softmax fails with the full registry listed, before any model
     # or training work (settled_backend_names() is None only mid-import,
@@ -60,8 +67,11 @@ def main():
     ap.add_argument("--N", type=int, default=16)
     ap.add_argument("--ckpt-dir", default=None,
                     help="restore params from a train.py checkpoint")
-    ap.add_argument("--warm-steps", type=int, default=120,
-                    help="if no checkpoint: quick-train so outputs are meaningful")
+    ap.add_argument("--warm-steps", type=int, default=None,
+                    help="if no checkpoint: quick-train so outputs are "
+                         "meaningful (default 120 with --smoke; 0 at "
+                         "published widths, whose optimizer state does not "
+                         "fit one chip). 0 serves seeded random params")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=24)
@@ -105,12 +115,14 @@ def main():
                     help="--speculative: draft tokens per verify round")
     ap.add_argument("--kernel", default="jnp", choices=("jnp", "pallas"),
                     help="--paged: decode-attention path; 'pallas' runs the "
-                         "fused block-table-walk kernel (bit-identical to "
-                         "the gather baseline; interpret mode off-TPU)")
+                         "fused block-table-walk kernel (compiled on a TPU; "
+                         "interpret mode, bit-identical to the gather "
+                         "baseline, on the CPU)")
     ap.add_argument("--shards", type=int, default=0,
                     help="tensor-parallel serving across N mesh devices "
                          "(heads + paged pool shard; greedy output is "
-                         "bit-identical to single-device). On CPU hosts "
+                         "bit-identical to single-device on the CPU, "
+                         "within bf16 rounding on a TPU). On CPU hosts "
                          "set XLA_FLAGS=--xla_force_host_platform_"
                          "device_count=N before launch")
     ap.add_argument("--prefill-chunk", type=int, default=None,
@@ -131,7 +143,16 @@ def main():
                     help="--kv-quant: scale rule (exaq = EXAQ-style "
                          "power-of-two scales, arxiv 2410.03185; "
                          "exaq_clamped = 5-bit-exponent hardware point)")
-    args = ap.parse_args()
+    return ap
+
+
+def parse_args(argv=None):
+    """Parse and cross-check launcher flags; returns ``(args,
+    serve_options)``. Flag conflicts fail here, before any model work."""
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    if args.warm_steps is None:
+        args.warm_steps = 120 if args.smoke else 0
     if (args.paged or args.prefix_share or args.speculative or args.shards) \
             and not args.continuous:
         ap.error("--paged/--prefix-share/--speculative/--shards require "
@@ -166,7 +187,18 @@ def main():
             preemption=args.preemption)
     except ValueError as e:
         ap.error(str(e))
+    return args, serve_options
 
+
+def build_engine(args) -> Engine:
+    """Model, parameters and engine for parsed launcher flags — the one
+    set-up the launcher and ``chip_smoke.py`` share.
+
+    Parameters come from ``--ckpt-dir``, else from ``--warm-steps`` of quick
+    training, else (``--warm-steps 0``) straight from a seeded
+    ``Model.init_split``, with no optimizer state built at all. The model
+    lives on ONE device; tensor-parallel serving (``--shards``) places its
+    own copy on the serving mesh inside ``Engine.serve``."""
     metered = get_backend(args.softmax).metered
     spec = SoftmaxSpec(args.softmax, PrecisionConfig(M=args.M, N=args.N)) \
         if metered else SoftmaxSpec(args.softmax)
@@ -176,19 +208,17 @@ def main():
         import dataclasses
         cfg = dataclasses.replace(cfg, kv_quant=True,
                                   kv_quant_scheme=args.kv_quant_scheme)
-    mesh = make_host_mesh()
-    model = Model(cfg, rules=ShardingRules(cfg.sharding_overrides), mesh=mesh)
+    mesh = make_host_mesh(jax.devices()[:1])
+    rules = ShardingRules(cfg.sharding_overrides)
+    model = Model(cfg, rules=rules, mesh=mesh)
     # warm training keeps the requested spec when its backend differentiates
     # (fp family, int, int_ste QAT); the non-differentiable substrates
     # (int_pallas, ap_sim) are serving-only choices, so their warm-up trains
     # against fp and the engine serves with the requested spec
     train_model = model if spec.backend().differentiable else Model(
-        cfg.with_softmax(SoftmaxSpec("fp")),
-        rules=ShardingRules(cfg.sharding_overrides), mesh=mesh)
-    corpus = SyntheticCorpus(cfg.vocab, seed=1234)
+        cfg.with_softmax(SoftmaxSpec("fp")), rules=rules, mesh=mesh)
 
     if args.ckpt_dir:
-        template, _ = model.init_split(jax.random.PRNGKey(0))
         from repro.training.optimizer import AdamW, constant_schedule
         from repro.training.step import init_state
         opt = AdamW(lr=constant_schedule(1e-3))
@@ -196,9 +226,10 @@ def main():
             args.ckpt_dir, init_state(train_model, opt, jax.random.PRNGKey(0)))
         params = state.params
         print(f"restored step {step} from {args.ckpt_dir}")
-    else:
+    elif args.warm_steps > 0:
         from repro.training.optimizer import AdamW, cosine_schedule
         from repro.training.step import init_state, make_train_step
+        corpus = SyntheticCorpus(cfg.vocab, seed=1234)
         opt = AdamW(lr=cosine_schedule(1e-2, 20, args.warm_steps))
         state = init_state(train_model, opt, jax.random.PRNGKey(0))
         step_fn = jax.jit(make_train_step(train_model, opt))
@@ -209,14 +240,24 @@ def main():
         params = state.params
         print(f"warm-trained {args.warm_steps} steps, "
               f"loss={float(met['loss']):.3f}")
+    else:
+        params, _ = model.init_split(jax.random.PRNGKey(0))
+        print("serving seeded random params (PRNGKey(0), no training)")
 
     sampler_kw = {}
     if args.sampler == "temperature":
         sampler_kw = {"temp": args.temp, "top_k": args.top_k}
     elif args.sampler in ("top_p", "nucleus"):
         sampler_kw = {"p": args.top_p, "temp": args.temp}
-    eng = Engine(model, params, max_new=args.max_new, sampler=args.sampler,
-                 eos_id=args.eos_id, **sampler_kw)
+    return Engine(model, params, max_new=args.max_new, sampler=args.sampler,
+                  eos_id=args.eos_id, **sampler_kw)
+
+
+def main(argv=None):
+    use_compile_cache()
+    args, serve_options = parse_args(argv)
+    eng = build_engine(args)
+    cfg = eng.model.cfg
     if args.continuous:
         from repro.serving.scheduler import random_trace
         reqs = random_trace(args.requests, cfg.vocab, seed=777,
@@ -273,6 +314,7 @@ def main():
                     and rep.cost_draft.cycles:
                 print(f"  draft phase: {rep.cost_draft.describe()}")
         return
+    corpus = SyntheticCorpus(cfg.vocab, seed=1234)
     prompts = corpus.sample(args.batch, args.prompt_len, seed=777)[:, :args.prompt_len]
     mode = "eager" if args.eager else "fused"
     res = eng.generate(prompts, report_cost=True, mode=mode)  # compile + run

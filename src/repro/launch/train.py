@@ -23,6 +23,7 @@ from repro.data.sharding import shard_batch
 from repro.distributed.straggler import StragglerMonitor, mitigate
 from repro.data.synthetic import SyntheticCorpus, family_batch
 from repro.distributed.sharding import ShardingRules, use_mesh
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.model import Model
 from repro.training.optimizer import AdamW, cosine_schedule
@@ -46,6 +47,7 @@ def main():
     ap.add_argument("--grad-compress", action="store_true")
     ap.add_argument("--production-mesh", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     spec = SoftmaxSpec(args.softmax, PrecisionConfig(M=args.M, N=args.N)) \
         if args.softmax == "int" else SoftmaxSpec(args.softmax)

@@ -238,6 +238,7 @@ def paged_write_block(pool, table, new, cache_pos):
     lb, off = pos // bs, pos % bs
     pb = jnp.take_along_axis(table, jnp.clip(lb, 0, n_log - 1), axis=1)
     pb = jnp.where(lb >= n_log, nb, pb)
+    new = new.reshape((b, t) + pool.shape[2:])
     return pool.at[pb, off].set(new.astype(pool.dtype), mode="drop")
 
 
@@ -289,17 +290,29 @@ def paged_gather(pool, table):
     return pages.reshape((b, n * bs) + pool.shape[2:])
 
 
+def paged_gather_heads(pool, table, kv_heads: int):
+    """:func:`paged_gather` of a K/V head pool ``[NB, BS, KV * D]`` (the
+    heads of one position side by side, the layout the fused kernel reads
+    one head of as an aligned tile) as the contiguous ``[B, L, KV, D]``
+    view the attention math takes."""
+    g = paged_gather(pool, table)
+    return g.reshape(g.shape[:2] + (kv_heads, -1))
+
+
 def paged_write(pool, table, new, cache_pos):
     """Write one entry per batch row into the pool at ``cache_pos`` through
     the block table. ``new`` [B, ...]; ``cache_pos`` scalar or [B]. Rows
     whose position is out of range (parked slots at cache_len) or whose
-    table entry is the NB sentinel scatter out of bounds and are dropped."""
+    table entry is the NB sentinel scatter out of bounds and are dropped.
+    Each entry is reshaped to the pool's row, so a K/V head pool
+    [NB, BS, KV * D] takes ``new`` as [B, KV, D]."""
     nb, bs = pool.shape[:2]
     b, n_log = table.shape
     pos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (b,))
     lb, off = pos // bs, pos % bs
     pb = jnp.take_along_axis(table, jnp.clip(lb, 0, n_log - 1)[:, None], 1)[:, 0]
     pb = jnp.where(lb >= n_log, nb, pb)
+    new = new.reshape((b,) + pool.shape[2:])
     return pool.at[pb, off].set(new.astype(pool.dtype), mode="drop")
 
 
@@ -307,13 +320,14 @@ def _attend_paged_fused(p, q, new_cache, positions, cfg, ctx: Ctx, kind,
                         backend):
     """Attend straight against the paged pools via the block-table-walking
     Pallas kernel (``kernels/paged_attention``) — no dense gather. Bit-exact
-    vs gather + ``backend.apply``; see the kernel module docstring for the
-    rounding contract. ``positions`` [B, T] absolute query positions."""
+    vs gather + ``backend.apply`` in interpret mode; see the kernel module
+    docstring for the rounding contract (compiled on a TPU it agrees within
+    bf16 rounding). ``positions`` [B, T] absolute query positions."""
     from repro.kernels.paged_attention import ops as paged_ops
 
     b, t, h, dh = q.shape
     table = new_cache["table"]
-    kvh = new_cache["k"].shape[2]
+    kvh = cfg.n_kv_heads
     l_max = table.shape[1] * new_cache["k"].shape[1]
     # same score shape/heads the gather path records — metering is invariant
     # to the execution substrate
@@ -342,8 +356,8 @@ def _shard_paged(new_cache, ctx: Ctx):
     NamedSharding across every donated decode/verify step (no relayout, no
     retrace)."""
     out = dict(new_cache)
-    out["k"] = ctx.shard(out["k"], (None, None, "kv_heads", None))
-    out["v"] = ctx.shard(out["v"], (None, None, "kv_heads", None))
+    out["k"] = ctx.shard(out["k"], (None, None, "kv_heads"))
+    out["v"] = ctx.shard(out["v"], (None, None, "kv_heads"))
     if "k_scale" in out:
         out["k_scale"] = ctx.shard(out["k_scale"], (None, None, "kv_heads"))
         out["v_scale"] = ctx.shard(out["v_scale"], (None, None, "kv_heads"))
@@ -384,16 +398,17 @@ def _attn_decode_paged(p, x, cache, cache_pos, cfg, ctx: Ctx, positions, kind):
                                (b,))[:, None]
         return _attend_paged_fused(p, q, new_cache, pos, cfg, ctx, kind,
                                    backend), new_cache
+    kvh = cfg.n_kv_heads
     if "k_scale" in cache:
-        k = kv_dequantize(paged_gather(new_cache["k"], table),
+        k = kv_dequantize(paged_gather_heads(new_cache["k"], table, kvh),
                           paged_gather(new_cache["k_scale"], table),
                           ctx.dtype)
-        v = kv_dequantize(paged_gather(new_cache["v"], table),
+        v = kv_dequantize(paged_gather_heads(new_cache["v"], table, kvh),
                           paged_gather(new_cache["v_scale"], table),
                           ctx.dtype)
     else:
-        k = paged_gather(new_cache["k"], table)
-        v = paged_gather(new_cache["v"], table)
+        k = paged_gather_heads(new_cache["k"], table, kvh)
+        v = paged_gather_heads(new_cache["v"], table, kvh)
     k = ctx.shard(k, ("batch", None, "kv_heads", None))
     v = ctx.shard(v, ("batch", None, "kv_heads", None))
     l_max = k.shape[1]
@@ -411,7 +426,8 @@ def attn_prefill_tail(p, x, prefix_k, prefix_v, cfg, ctx: Ctx, positions,
     """Prefill the unshared prompt tail against a shared-prefix cache.
 
     ``x`` embeds tokens[prefix_len:]; ``prefix_k``/``prefix_v`` [B, s, KV, D]
-    are the prefix K/V gathered from shared pool blocks (the exact bf16
+    (or [B, s, KV * D] straight from the pool) are the prefix K/V gathered
+    from shared pool blocks (the exact bf16
     values a full prefill would have computed and cached for those
     positions, so the tail's attention rows — and its own K/V — match the
     full prefill bit for bit). Returns (y, {"k","v"} tail cache [B, T, ...]).
@@ -424,6 +440,10 @@ def attn_prefill_tail(p, x, prefix_k, prefix_v, cfg, ctx: Ctx, positions,
     bit-identical to the private whole-prefill path."""
     b, t, _ = x.shape
     q, k_t, v_t = project_qkv(p, x, cfg, ctx, positions)
+    # a prefix read back from pool blocks has its heads side by side
+    # ([B, s, KV * D]); a contiguous chunk prefix is already [B, s, KV, D]
+    prefix_k = prefix_k.reshape(prefix_k.shape[:2] + (cfg.n_kv_heads, -1))
+    prefix_v = prefix_v.reshape(prefix_v.shape[:2] + (cfg.n_kv_heads, -1))
     if getattr(cfg, "kv_quant", False):
         scheme = getattr(cfg, "kv_quant_scheme", "absmax")
         kq, ks, k_t = kv_fake_quant(k_t, scheme)
@@ -449,7 +469,7 @@ def attn_decode(p, x, cache, cache_pos, cfg, ctx: Ctx, positions,
     """Single-token decode. cache: {"k","v"} [B, L, KV, D] (kv_seq-sharded:
     split-KV / flash-decoding style), optionally int8-quantized with
     per-(position, head) scales ({"k_scale","v_scale"} present), or the
-    paged layout ({"table" present}: pool [NB, BS, KV, D] + block table).
+    paged layout ({"table" present}: pool [NB, BS, KV * D] + block table).
     cache_pos: int32 current length — scalar (uniform batch) or [B]
     (per-slot positions, continuous batching)."""
     if "table" in cache:
@@ -529,13 +549,15 @@ def attn_verify(p, x, cache, cache_pos, cfg, ctx: Ctx, positions,
             return _attend_paged_fused(p, q, new_cache,
                                        positions.astype(jnp.int32), cfg,
                                        ctx, kind, backend), new_cache
+        kvh = cfg.n_kv_heads
         if "k_scale" in cache:
-            k = kv_dequantize(paged_gather(kp, table),
+            k = kv_dequantize(paged_gather_heads(kp, table, kvh),
                               paged_gather(ksp, table), ctx.dtype)
-            v = kv_dequantize(paged_gather(vp, table),
+            v = kv_dequantize(paged_gather_heads(vp, table, kvh),
                               paged_gather(vsp, table), ctx.dtype)
         else:
-            k, v = paged_gather(kp, table), paged_gather(vp, table)
+            k = paged_gather_heads(kp, table, kvh)
+            v = paged_gather_heads(vp, table, kvh)
         k = ctx.shard(k, ("batch", None, "kv_heads", None))
         v = ctx.shard(v, ("batch", None, "kv_heads", None))
     elif "k_scale" in cache:

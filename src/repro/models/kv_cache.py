@@ -115,14 +115,18 @@ def cache_zeros(cfg, batch: int, cache_len: int, enc_len: int = 0):
 
 
 def _paged_attn_cache(make, L, nb, bs, slots, n_logical, cfg):
+    # K/V pools keep one position's heads side by side ([..., KV * D]), so
+    # the fused paged-decode kernel fetches head h of a page as one
+    # tile-aligned [BS, D] block (see kernels/paged_attention/kernel.py)
+    row = cfg.n_kv_heads * cfg.d_head
     if getattr(cfg, "kv_quant", False):
-        d = {"k": make((L, nb, bs, cfg.n_kv_heads, cfg.d_head), jnp.int8),
-             "v": make((L, nb, bs, cfg.n_kv_heads, cfg.d_head), jnp.int8),
+        d = {"k": make((L, nb, bs, row), jnp.int8),
+             "v": make((L, nb, bs, row), jnp.int8),
              "k_scale": make((L, nb, bs, cfg.n_kv_heads), jnp.float32),
              "v_scale": make((L, nb, bs, cfg.n_kv_heads), jnp.float32)}
     else:
-        d = {"k": make((L, nb, bs, cfg.n_kv_heads, cfg.d_head), KV_DTYPE),
-             "v": make((L, nb, bs, cfg.n_kv_heads, cfg.d_head), KV_DTYPE)}
+        d = {"k": make((L, nb, bs, row), KV_DTYPE),
+             "v": make((L, nb, bs, row), KV_DTYPE)}
     d["table"] = make((L, slots, n_logical), jnp.int32, fill=nb)
     return d
 
@@ -191,6 +195,7 @@ def paged_scatter(cache, values, slot, table_row, pb, offs, t0: int, t1: int):
                     out[k] = leaf.at[:, slot, :].set(table_row)
                 else:
                     vals = v[k][:, 0, t0:t1]
+                    vals = vals.reshape(vals.shape[:2] + leaf.shape[3:])
                     out[k] = leaf.at[:, pb, offs].set(vals.astype(leaf.dtype))
             return out
         if isinstance(c, dict):
@@ -397,10 +402,10 @@ def paged_cache_axes(cfg, slots: int, cache_len: int, block_size: int,
     every shard."""
     def axes_for(shape, dtype):
         rank = len(shape)
-        if rank == 5 and shape[3] == cfg.n_kv_heads:   # pool [L,NB,BS,KV,Dh]
-            return ("stacked", None, None, "kv_heads", None)
-        if rank == 4 and shape[-1] == cfg.n_kv_heads and \
-                getattr(cfg, "kv_quant", False):       # scales [L,NB,BS,KV]
+        if rank == 4 and cfg.attention != "mla" and (
+                shape[-1] == cfg.n_kv_heads * cfg.d_head  # pool [L,NB,BS,KV*Dh]
+                or (shape[-1] == cfg.n_kv_heads and       # scales [L,NB,BS,KV]
+                    getattr(cfg, "kv_quant", False))):
             return ("stacked", None, None, "kv_heads")
         if rank == 4 and cfg.attention == "mla" and \
                 shape[-1] == cfg.kv_lora_rank:         # c_kv [L,NB,BS,r]

@@ -216,8 +216,8 @@ def _mla_attend_paged_fused(p, q_nope, q_rope, new_cache, positions, cfg,
                             ctx: Ctx, backend, b, s):
     """Absorbed attention straight against the paged latent pools via the
     block-table-walking Pallas kernel — no dense gather. Bit-exact vs
-    gather + ``_mla_attend`` (the kernel reproduces the two-dot "semi"
-    rounding of the score sum; see its module docstring)."""
+    gather + ``_mla_attend`` in interpret mode (the kernel reproduces the
+    two-dot "semi" rounding of the score sum; see its module docstring)."""
     from repro.kernels.paged_attention import ops as paged_ops
 
     h, dn, dr = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim

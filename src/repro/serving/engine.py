@@ -287,6 +287,13 @@ def make_spec_step_fn(model: Model, verifier: Callable, k: int) -> Callable:
     return spec_step
 
 
+def _default_num_blocks(slots: int, n_logical: int, prefix_share: bool):
+    """Pool size when ``ServeOptions.num_blocks`` is None: every slot's worst
+    case, plus one request's worth of slack for the cross-request prefix
+    cache to live in."""
+    return slots * n_logical + (n_logical if prefix_share else 0)
+
+
 class Engine:
     def __init__(self, model: Model, params, max_new: int = 64,
                  sampler: str = "greedy", eos_id: Optional[int] = None,
@@ -663,6 +670,35 @@ class Engine:
                 donate_argnums=(1,))
         return self._serve_jits[key]
 
+    def decode_executor(self, kernel: str = "jnp", mesh=None):
+        """``(model, params)`` that ``serve`` decodes with under ``kernel``
+        on ``mesh`` (None: one device) — for callers that replay decode
+        steps outside the serve loop, e.g. to compare two executors'
+        logits on one cache."""
+        if mesh is None:
+            return self._kernel_model(kernel), self.params
+        return (self._serving_model(kernel, mesh),
+                self._mesh_exec(mesh)["params"])
+
+    def lower_serve_step(self, options: ServeOptions, cache_len: int):
+        """Lower — without running — the single-device paged decode step
+        ``serve(..., options=options)`` runs at ``cache_len`` (the served
+        report's ``cache_len``), so callers can read the program it
+        dispatches (``.as_text()``: which kernels, which custom calls)."""
+        opt = options
+        if not opt.paged:
+            raise ValueError("lower_serve_step covers paged serving")
+        cfg = self._variant_model(opt.softmax_kind).cfg
+        s, bs = opt.slots, opt.block_size
+        nb = opt.num_blocks or _default_num_blocks(s, cache_len // bs,
+                                                   opt.prefix_share)
+        cache = kv_cache.paged_cache_struct(cfg, s, cache_len, bs, nb)
+        sds = jax.ShapeDtypeStruct
+        step = self._get_serve_step(opt.kernel, None, opt.softmax_kind)
+        return step.lower(self.params, cache, sds((s, 1), jnp.int32),
+                          sds((s,), jnp.int32), sds((s, 2), jnp.uint32),
+                          sds((s,), jnp.bool_))
+
     def _get_spec_step(self, draft_k: int, kernel: str = "jnp", mesh=None,
                        softmax_kind: Optional[str] = None):
         """The compiled draft-verify step for one (draft depth, kernel[,
@@ -772,7 +808,8 @@ class Engine:
         ``kernel="pallas"`` (paged, integer-softmax models only) runs decode
         and verify steps through the fused block-table attention kernel
         (``kernels/paged_attention``) instead of gather-then-attend —
-        bit-identical outputs, one compiled step per geometry exactly like
+        bit-identical outputs in interpret mode (compiled on a TPU, logits
+        agree within bf16 rounding), one compiled step per geometry exactly like
         the default executor, and composes with ``prefix_share`` and
         ``speculative``.
 
@@ -787,7 +824,9 @@ class Engine:
         the donated sharded carry. Head counts (or the latent rank) that do
         not divide the shard count raise up front
         (``serving.sharded.validate_serving_shards``); greedy outputs stay
-        token-identical to single-device serving and the path composes with
+        token-identical to single-device serving on the CPU (on a TPU the
+        sharded program's logits agree within bf16 rounding, so tokens can
+        part at near-ties) and the path composes with
         ``paged``/``prefix_share``/``speculative``/``kernel``.
 
         ``prefill_chunk=N`` bounds the prompt tokens prefilled per engine
@@ -899,10 +938,8 @@ class Engine:
             C = -(-C // block_size) * block_size     # round up to block grid
             n_logical = C // block_size
             if num_blocks is None:
-                # every slot's worst case, plus one request's worth of slack
-                # for the cross-request prefix cache to live in
-                num_blocks = slots * n_logical + (n_logical if prefix_share
-                                                  else 0)
+                num_blocks = _default_num_blocks(slots, n_logical,
+                                                 prefix_share)
             alloc = BlockAllocator(num_blocks, block_size)
             # debug/test handle: pool bookkeeping of the most recent serve
             # (tests assert allocator-state invariants across cache dtypes)

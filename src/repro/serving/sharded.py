@@ -194,13 +194,15 @@ def check_sharded_consistency(engine, requests, shards: Optional[int] = None,
     token-identical (head-parallel attention is bitwise; the row-parallel
     output projections reduce in a different order, which greedy argmax
     absorbs). Returns a report; ``bool(report)`` is the pass/fail."""
+    if mesh is None and shards is None:
+        raise ValueError("check_sharded_consistency needs shards=N or a "
+                         "mesh to compare against one device")
     reqs = list(requests)
     base = engine.serve(reqs, **serve_kw)
     shrd = engine.serve(reqs, mesh=mesh, shards=shards, **serve_kw)
     base_by, shrd_by = base.by_rid(), shrd.by_rid()
     bad = [rid for rid in sorted(base_by)
            if not np.array_equal(base_by[rid].tokens, shrd_by[rid].tokens)]
-    n = (mesh.shape[MODEL_AXIS] if mesh is not None
-         else (shards if shards is not None else len(jax.devices())))
+    n = mesh.shape[MODEL_AXIS] if mesh is not None else shards
     return ConsistencyReport(matched=not bad, n_requests=len(reqs),
                              shards=int(n), mismatched_rids=bad)

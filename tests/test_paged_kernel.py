@@ -32,6 +32,7 @@ from repro.core.int_softmax import int_softmax
 from repro.core.precision import BEST
 from repro.core.softmax_variants import SoftmaxSpec
 from repro.kernels.paged_attention import ops
+from repro.kernels.paged_attention.kernel import aligned_pages
 from repro.models import build_model, kv_cache
 from repro.models.attention import paged_gather
 
@@ -89,6 +90,11 @@ def _ref_mla(q_lat, q_rope, c_pool, kr_pool, table, positions, scale):
     return jnp.einsum("bhql,blr->bqhr", w, c_kv)
 
 
+def _merged(pool):
+    """[NB, BS, KV, D] -> the kernel's [NB, BS, KV * D] pool layout."""
+    return pool.reshape(pool.shape[:2] + (-1,))
+
+
 def _mixed_table(rng, B, NLOG, NB, BS, T):
     """Per-row tables with a random live prefix and NB sentinels after it;
     positions inside the live region."""
@@ -133,7 +139,8 @@ def test_dense_kernel_bitexact(bs, nlog, t, kvh, window, quant):
     scale = D ** -0.5
     want = _ref_dense(q, k_pool, v_pool, table, positions, scale=scale,
                       window=window, k_scale=k_scale, v_scale=v_scale)
-    got = ops.paged_attend_dense(q, k_pool, v_pool, table, positions, BEST,
+    got = ops.paged_attend_dense(q, _merged(k_pool), _merged(v_pool), table,
+                                 positions, BEST,
                                  scale=scale, window=window, k_scale=k_scale,
                                  v_scale=v_scale, interpret=True)
     assert jnp.array_equal(want.astype(jnp.float32),
@@ -150,10 +157,18 @@ def test_dense_kernel_bitexact_dtype(dtype):
     table, positions = _mixed_table(r, B, nlog, NB, bs, t)
     scale = D ** -0.5
     want = _ref_dense(q, k_pool, v_pool, table, positions, scale=scale)
-    got = ops.paged_attend_dense(q, k_pool, v_pool, table, positions, BEST,
-                                 scale=scale, interpret=True)
-    assert jnp.array_equal(want.astype(jnp.float32),
-                           got.astype(jnp.float32))
+    got = ops.paged_attend_dense(q, _merged(k_pool), _merged(v_pool), table,
+                                 positions, BEST, scale=scale, interpret=True)
+    if dtype == jnp.bfloat16:
+        assert jnp.array_equal(want.astype(jnp.float32),
+                               got.astype(jnp.float32))
+    else:
+        # float32 dots have no bf16 rounding step to hide the order of the
+        # f32 accumulation, and XLA:CPU sums a [ROWS, L] x [L, D] dot in a
+        # different order than the reference's batched einsum: the outputs
+        # (|x| < 4) then differ by a few f32 ulps (2.4e-7 at |x| ~ 2)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=1e-6)
 
 
 def test_dense_kernel_bitexact_4k():
@@ -172,8 +187,8 @@ def test_dense_kernel_bitexact_4k():
     table = jnp.asarray(table)
     scale = D ** -0.5
     want = _ref_dense(q, k_pool, v_pool, table, positions, scale=scale)
-    got = ops.paged_attend_dense(q, k_pool, v_pool, table, positions, BEST,
-                                 scale=scale, interpret=True)
+    got = ops.paged_attend_dense(q, _merged(k_pool), _merged(v_pool), table,
+                                 positions, BEST, scale=scale, interpret=True)
     assert jnp.array_equal(want.astype(jnp.float32),
                            got.astype(jnp.float32))
 
@@ -211,11 +226,34 @@ def test_paged_gather_zeros_sentinels():
         assert np.all(np.asarray(out[b, n]) == 0.0), (b, n)
 
 
+def test_interpret_only_on_cpu(monkeypatch):
+    """Kernels run in the Pallas interpreter on the CPU (how this suite
+    runs), compile on a TPU, and refuse any other platform instead of
+    silently ceasing to be a kernel there; an explicit flag always wins."""
+    import repro.kernels as kernels
+
+    assert kernels.resolve_interpret(None) is True     # suite runs on cpu
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "tpu")
+    assert kernels.resolve_interpret(None) is False
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu' is neither"):
+        kernels.resolve_interpret(None)
+    assert kernels.resolve_interpret(True) is True
+    assert kernels.resolve_interpret(False) is False
+
+
 def test_choose_tiles_divides_and_fits():
-    pps = ops.choose_tiles(4, 256, 16, 64, 64, 2, False)
-    assert pps in (8, 4, 2, 1) and 256 % pps == 0
-    # a table length not divisible by 8 falls back to a dividing candidate
-    assert ops.choose_tiles(4, 12, 16, 64, 64, 2, False) in (4, 2, 1)
+    """pps * block_size fills whole 128-lane tiles (the score slab's store
+    offset must be lane-aligned on the chip) and divides the table."""
+    for bs, nlog in [(16, 256), (8, 64), (64, 20), (256, 3)]:
+        pps = ops.choose_tiles(4, nlog, bs, 64, 64, 2, False)
+        base = aligned_pages(bs)
+        assert pps * bs % 128 == 0 and pps % base == 0
+        assert (-(-nlog // base) * base) % pps == 0
+    # a table that is not a whole number of aligned steps is padded to the
+    # next one, and the step then divides the padded length
+    assert ops.choose_tiles(4, 12, 16, 64, 64, 2, False) == 16
+    assert ops.choose_tiles(4, 34, 16, 64, 64, 2, False) == 8
 
 
 def test_choose_tiles_rejects_loudly():

@@ -115,6 +115,13 @@ def test_serve_shards_validates_before_placement():
 
 # ------------------------------------------------------------ pool accounting
 
+def test_consistency_check_needs_shards():
+    """The check compares one device with a NAMED shard count: no default
+    that quietly spans however many devices this host has."""
+    with pytest.raises(ValueError, match="shards=N or a mesh"):
+        check_sharded_consistency(None, [])
+
+
 def test_pool_report_partitions_pool_bytes():
     """Analytic accounting over the REAL pool builders: partitioned bytes
     divide by shards, replicated bytes are paid per device, and one shard

@@ -88,7 +88,7 @@ def _paged_install(cfg, cache, pcache, B, C, bs):
                         continue
                     v = sc[k][:, b]
                     ll = v.shape[0]
-                    vv = v.reshape((ll, n_log, bs) + v.shape[2:])
+                    vv = v.reshape((ll, n_log, bs) + out[k].shape[3:])
                     out[k] = out[k].at[:, ids].set(vv.astype(out[k].dtype))
             return out
         if isinstance(pc, dict):
