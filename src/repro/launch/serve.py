@@ -8,7 +8,11 @@ decode loop with in-scan sampling and a donated cache — see
 serving/engine.py); ``--eager`` falls back to the per-token dispatch loop for
 comparison. ``--continuous`` switches to the continuous-batching scheduler:
 a trace of staggered mixed-length requests served through slot-based KV
-caching (``Engine.serve``), with per-request latency and attributed AP cost.
+caching (``Engine.serve``), with per-request latency and attributed AP cost,
+and where the serve loop's host time went: each phase's self seconds, count
+and longest span (``ServeReport.host_s``; ``loop`` is the rest), and the
+gaps between tokens by the admission prefill they held
+(``ServeReport.gap_kinds``).
 
     PYTHONPATH=src python -m repro.launch.serve --arch llama2-7b --smoke \
         --softmax int --max-new 32 --sampler top_p --top-p 0.9
@@ -253,6 +257,19 @@ def build_engine(args) -> Engine:
                   eos_id=args.eos_id, **sampler_kw)
 
 
+def host_loop_lines(rep) -> list:
+    """The serve loop's host phases and gap kinds of one ``ServeReport``, as
+    ``--continuous`` prints them."""
+    lines = [f"host loop ({rep.wall_s * 1e3:.1f} ms wall): phase, "
+             "self ms, count, longest ms"]
+    for name, (secs, n, longest) in rep.host_s.items():
+        lines.append(f"  {name:<12} {secs * 1e3:10.3f} {n:7d} "
+                     f"{longest * 1e3:10.3f}")
+    lines.append("gaps between tokens: " + ", ".join(
+        f"{kind} {n}" for kind, n in rep.gap_kinds.items()))
+    return lines
+
+
 def main(argv=None):
     use_compile_cache()
     args, serve_options = parse_args(argv)
@@ -285,6 +302,7 @@ def main(argv=None):
               f"{paged_note}{spec_note}")
         print(f"request latency p50={np.percentile(lat, 50) * 1e3:.1f} ms "
               f"p99={np.percentile(lat, 99) * 1e3:.1f} ms")
+        print("\n".join(host_loop_lines(rep)))
         if args.prefill_chunk is not None or args.preemption:
             print(f"sla: prefill_chunk={rep.prefill_chunk or 'off'} "
                   f"(max prefill/step {rep.max_prefill_per_step}), "

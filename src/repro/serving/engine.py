@@ -48,7 +48,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 import warnings
 from collections import OrderedDict
 from typing import Callable, Dict, List, Optional, Sequence
@@ -65,6 +64,7 @@ from repro.serving.sampler import make_sampler, make_spec_verifier
 from repro.serving.scheduler import (
     BlockAllocator, Request, SlotScheduler, prefix_keys,
 )
+from repro.serving.spans import LoopSpans
 from repro.serving.speculative import make_proposer
 
 _legacy_serve_warned = False
@@ -145,6 +145,12 @@ class ServeReport:
     resumes: int = 0
     leaked_blocks: int = 0              # pool blocks unaccounted after drain
     class_latency: Optional[dict] = None  # per-priority-class latency/SLA
+    # host loop (serving/spans.py): phase -> [self seconds, count, longest
+    # span]; "loop" is the rest of wall_s. Gap kinds count the gaps between
+    # tokens holding a whole-prompt ("full") or prefix-hit ("tail")
+    # admission prefill, or neither ("plain").
+    host_s: Dict[str, List[float]] = dataclasses.field(default_factory=dict)
+    gap_kinds: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     @property
     def acceptance_rate(self) -> float:
@@ -1017,9 +1023,7 @@ class Engine:
         keys = np.zeros((slots, 2), np.uint32)
         done = np.ones((slots,), bool)
 
-        wall0 = time.perf_counter()
-        queued_wall: Dict[int, float] = {}
-        emit_wall: Dict[int, List[float]] = {}    # rid -> wall per emission
+        spans = LoopSpans()   # the loop's clock, host phases and gap kinds
         first_at: Dict[int, float] = {}           # rid -> serve clock of TTFT
         results: Dict[int, RequestResult] = {}
         # chunked prefill: slot -> in-flight prompt-commit job, processed one
@@ -1044,12 +1048,11 @@ class Engine:
                     alloc.release_block(b)
             if proposer is not None:
                 proposer.release(slot)
-            q0 = queued_wall.get(r.rid, wall0)
-            ew = emit_wall.pop(r.rid, [])
+            ttft_s, tbt_s, latency_s = spans.timings(r.rid)
             results[r.rid] = RequestResult(
                 rid=r.rid, tokens=toks, prompt_len=r.prompt_len,
                 done=st.done, admitted_at=st.admitted_at, finished_at=t,
-                latency_s=time.perf_counter() - q0,
+                latency_s=latency_s,
                 cost=attr.report_for(r.rid) if attr else None,
                 shared_prefix=shared_of.get(r.rid, 0),
                 drafted=st.drafted, accepted=st.accepted,
@@ -1057,8 +1060,7 @@ class Engine:
                 deadline_met=(None if r.deadline is None
                               else (t - r.arrival) <= r.deadline),
                 first_token_at=first_at.pop(r.rid, st.admitted_at),
-                ttft_s=(ew[0] - q0) if ew else 0.0,
-                tbt_s=[b - a for a, b in zip(ew, ew[1:])],
+                ttft_s=ttft_s, tbt_s=tbt_s,
                 preempts=st.preempts)
 
         def prompt_batch(req: Request, lo: int = 0, hi=None) -> dict:
@@ -1196,25 +1198,28 @@ class Engine:
         def activate(slot: int, req: Request, logits) -> None:
             """Sample the first token from the (last) prefill logits and turn
             the reserved slot into a live decode lane."""
-            if mesh is not None:
-                # detach admission logits from the mesh: the eager sampler
-                # should not dispatch an SPMD program per admit
-                logits = jnp.asarray(np.asarray(logits))
-            k = jax.random.PRNGKey(req.seed)
-            k, sub = jax.random.split(k)
-            first = int(self.sample(logits[:, -1], sub)[0])
-            done0 = self.eos_id is not None and first == self.eos_id
-            if proposer is not None:
-                proposer.admit(slot, np.asarray(req.prompt, np.int32),
-                               first, req.prompt_len)
-            sched.slots[slot].prefilling = False
-            sched.install(slot, first, done0)
-            tok[slot, 0] = first
-            pos[slot] = req.prompt_len
-            keys[slot] = np.asarray(k, np.uint32)
-            done[slot] = done0
-            first_at[req.rid] = t
-            emit_wall.setdefault(req.rid, []).append(time.perf_counter())
+            with spans.phase("first_token", rid=req.rid):
+                if mesh is not None:
+                    # detach admission logits from the mesh: the eager
+                    # sampler should not dispatch an SPMD program per admit
+                    logits = jnp.asarray(np.asarray(logits))
+                k = jax.random.PRNGKey(req.seed)
+                k, sub = jax.random.split(k)
+                first = int(self.sample(logits[:, -1], sub)[0])
+                done0 = self.eos_id is not None and first == self.eos_id
+                if proposer is not None:
+                    proposer.admit(slot, np.asarray(req.prompt, np.int32),
+                                   first, req.prompt_len)
+                sched.slots[slot].prefilling = False
+                sched.install(slot, first, done0)
+                tok[slot, 0] = first
+                pos[slot] = req.prompt_len
+                keys[slot] = np.asarray(k, np.uint32)
+                done[slot] = done0
+                first_at[req.rid] = t
+                spans.activated("tail" if shared_of.get(req.rid, 0) > 0
+                                else "full")
+                spans.emitted(req.rid)
             if sched.slot_done(slot):
                 finish(slot)
 
@@ -1296,7 +1301,8 @@ class Engine:
         def handle_admission(slot: int, req: Request) -> None:
             nonlocal cache, shared_tok, prefill_tok, pf_this_step
             if req.rid in swap_store:
-                resume(slot, req)
+                with spans.phase("resume", rid=req.rid):
+                    resume(slot, req)
                 return
             P = req.prompt_len
             if alloc is not None:
@@ -1377,19 +1383,22 @@ class Engine:
                 activate(slot, req, logits)
 
         while sched.unfinished:
+            spans.tick()
             sched.advance(t)
             pf_this_step = 0
-            for r in sched.queue:
-                queued_wall.setdefault(r.rid, time.perf_counter())
+            spans.queued(r.rid for r in sched.queue)
             while True:
                 for slot, req in sched.admit(t):
-                    handle_admission(slot, req)
+                    with spans.phase("admission", rid=req.rid):
+                        handle_admission(slot, req)
                 if not preemption:
                     break
                 victim = sched.preempt_victim(t)
                 if victim is None:
                     break
-                swap_out(victim)
+                with spans.phase("swap_out",
+                                 rid=sched.slots[victim].request.rid):
+                    swap_out(victim)
             progressed = False
             if chunk_jobs and (pf_this_step == 0
                                or not sched.active_slots()):
@@ -1405,70 +1414,75 @@ class Engine:
                 cache, out_d, n_d, keys_d = spec_step(
                     params, cache, jnp.asarray(tok), jnp.asarray(drafts),
                     jnp.asarray(pos), jnp.asarray(keys))
-                out_np = np.asarray(out_d)
-                n_np = np.asarray(n_d)
-                keys = np.array(keys_d)      # copy: host arrays stay writable
+                with spans.phase("step_sync", step=steps):
+                    out_np = np.asarray(out_d)
+                    n_np = np.asarray(n_d)
+                    keys = np.array(keys_d)  # copy: host arrays stay writable
+                with spans.phase("emit", step=steps):
+                    now = spans.now()
+                    if attr is not None:
+                        rids = sched.active_requests()
+                        attr.record_step(verify_cost, rids, kind="verify")
+                        if draft_cost is not None:
+                            attr.record_step(draft_cost, rids, kind="draft")
+                    for slot in active:
+                        st = sched.slots[slot]
+                        r = st.request
+                        n_emit = int(n_np[slot])
+                        budget = r.max_new - len(st.generated)
+                        # commit emissions host-side, truncating at EOS or
+                        # the request budget — exactly where the
+                        # non-speculative loop would have stopped stepping
+                        # this slot
+                        used = 0
+                        for tk in out_np[slot, :n_emit]:
+                            st.generated.append(int(tk))
+                            spans.emitted(r.rid, now)
+                            used += 1
+                            if self.eos_id is not None and \
+                                    int(tk) == self.eos_id:
+                                st.done = True
+                                done[slot] = True
+                                break
+                            if len(st.generated) >= r.max_new:
+                                break
+                        # draft accounting counts only slots that could have
+                        # been committed (the budget cap is known up front)
+                        # and were: acceptance_rate measures useful drafting,
+                        # not verifier hits past the request's end
+                        sched.record_draft(slot, min(draft_k, budget),
+                                           min(used, n_emit - 1))
+                        proposer.observe(slot, out_np[slot, :used])
+                        tok[slot, 0] = st.generated[-1]
+                        pos[slot] += n_emit
+                        if sched.slot_done(slot):
+                            finish(slot)
                 steps += 1
-                now = time.perf_counter()
-                if attr is not None:
-                    rids = sched.active_requests()
-                    attr.record_step(verify_cost, rids, kind="verify")
-                    if draft_cost is not None:
-                        attr.record_step(draft_cost, rids, kind="draft")
-                for slot in active:
-                    st = sched.slots[slot]
-                    r = st.request
-                    n_emit = int(n_np[slot])
-                    budget = r.max_new - len(st.generated)
-                    # commit emissions host-side, truncating at EOS or the
-                    # request budget — exactly where the non-speculative
-                    # loop would have stopped stepping this slot
-                    used = 0
-                    ew = emit_wall.setdefault(r.rid, [])
-                    for tk in out_np[slot, :n_emit]:
-                        st.generated.append(int(tk))
-                        ew.append(now)
-                        used += 1
-                        if self.eos_id is not None and int(tk) == self.eos_id:
-                            st.done = True
-                            done[slot] = True
-                            break
-                        if len(st.generated) >= r.max_new:
-                            break
-                    # draft accounting counts only slots that could have
-                    # been committed (the budget cap is known up front) and
-                    # were: acceptance_rate measures useful drafting, not
-                    # verifier hits past the request's end
-                    sched.record_draft(slot, min(draft_k, budget),
-                                       min(used, n_emit - 1))
-                    proposer.observe(slot, out_np[slot, :used])
-                    tok[slot, 0] = st.generated[-1]
-                    pos[slot] += n_emit
-                    if sched.slot_done(slot):
-                        finish(slot)
                 t += 1.0
             elif active:
                 cache, toks_d, keys_d, done_d = serve_step(
                     params, cache, jnp.asarray(tok), jnp.asarray(pos),
                     jnp.asarray(keys), jnp.asarray(done))
-                toks_np = np.asarray(toks_d)
-                keys = np.array(keys_d)      # copy: host arrays stay writable
-                done_np = np.array(done_d)
+                with spans.phase("step_sync", step=steps):
+                    toks_np = np.asarray(toks_d)
+                    keys = np.array(keys_d)  # copy: host arrays stay writable
+                    done_np = np.array(done_d)
+                with spans.phase("emit", step=steps):
+                    now = spans.now()
+                    if attr is not None:
+                        attr.record_step(step_cost, sched.active_requests())
+                    for slot in active:
+                        st = sched.slots[slot]
+                        st.generated.append(int(toks_np[slot]))
+                        spans.emitted(st.request.rid, now)
+                        if self.eos_id is not None:
+                            st.done = bool(done_np[slot])
+                            done[slot] = done_np[slot]
+                        tok[slot, 0] = int(toks_np[slot])
+                        pos[slot] += 1
+                        if sched.slot_done(slot):
+                            finish(slot)
                 steps += 1
-                now = time.perf_counter()
-                if attr is not None:
-                    attr.record_step(step_cost, sched.active_requests())
-                for slot in active:
-                    st = sched.slots[slot]
-                    st.generated.append(int(toks_np[slot]))
-                    emit_wall.setdefault(st.request.rid, []).append(now)
-                    if self.eos_id is not None:
-                        st.done = bool(done_np[slot])
-                        done[slot] = done_np[slot]
-                    tok[slot, 0] = int(toks_np[slot])
-                    pos[slot] += 1
-                    if sched.slot_done(slot):
-                        finish(slot)
                 t += 1.0
             elif progressed:
                 t += 1.0    # chunk-only step: prompt commits still take time
@@ -1481,9 +1495,10 @@ class Engine:
             max_pf = max(max_pf, pf_this_step)
 
         ordered = [results[r.rid] for r in sorted(reqs, key=lambda q: q.rid)]
+        wall_s, host_s = spans.close()
         return ServeReport(
             results=ordered, steps=steps,
-            wall_s=time.perf_counter() - wall0, slots=slots, cache_len=C,
+            wall_s=wall_s, slots=slots, cache_len=C,
             cost=attr.total() if attr else None,
             paged=paged, block_size=block_size if paged else 0,
             prefill_tokens=prefill_tok, shared_prefill_tokens=shared_tok,
@@ -1500,7 +1515,8 @@ class Engine:
             preemptions=sched.preemptions, resumes=sched.resumes,
             leaked_blocks=(alloc.num_blocks - alloc.available())
             if alloc else 0,
-            class_latency=telemetry.class_latency_summary(ordered))
+            class_latency=telemetry.class_latency_summary(ordered),
+            host_s=host_s, gap_kinds=dict(spans.gap_kinds))
 
 
 def make_serve_step(model: Model, kind: str, max_new: int = 64,

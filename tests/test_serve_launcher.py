@@ -103,3 +103,21 @@ def test_lower_serve_step_and_decode_executor():
     assert model.cfg.softmax.kind == "int_pallas_paged"
     assert params is engine.params
     assert engine.decode_executor()[0] is engine.model
+
+
+def test_continuous_prints_the_host_loop_table(capsys):
+    """``--continuous`` reports where the serve loop's host time went: each
+    phase with its self time, count and longest span, the rest as ``loop``,
+    and the gaps between tokens by kind."""
+    from repro.launch.serve import main
+
+    main(["--arch", "olmo-1b", "--smoke", "--softmax", "int",
+          "--warm-steps", "0", "--max-new", "4", "--continuous", "--paged",
+          "--requests", "3", "--slots", "2", "--prompt-len", "8"])
+    out = capsys.readouterr().out
+    table = out[out.index("host loop ("):].splitlines()
+    phases = [row.split()[0] for row in table[1:8]]
+    assert phases == ["admission", "first_token", "step_sync", "emit",
+                      "swap_out", "resume", "loop"]
+    assert int(table[1].split()[2]) == 3         # one admission per request
+    assert table[8].startswith("gaps between tokens: plain ")
