@@ -25,7 +25,8 @@ from repro.core.int_softmax import int_softmax
 @register_backend("int_pallas_paged")
 class IntPallasPagedBackend(IntBackendBase):
     """Integer softmax whose paged-decode sites run the fused block-table
-    kernel (one VMEM residency per (slot, head); no dense gather)."""
+    kernel (one VMEM residency per (slot, block of kv heads); no dense
+    gather)."""
 
     name = "int_pallas_paged"
     fused_paged_decode = True

@@ -6,30 +6,37 @@ The gather-then-attend reference path (``models/attention.py``,
 roofline that is a memory term proportional to the pool's logical capacity,
 not to the tokens a slot has actually written. These kernels instead stream
 K/V **pages** straight from the global pool, one VMEM residency per
-(slot, kv-head) program:
+(slot, block of HPP kv-heads) program:
 
-  grid = (S, KV, n_pad / PPS)             dense / GQA
-  grid = (S,     n_pad / PPS)             MLA (latent cache is head-shared)
+  grid = (S, KV / HPP, n_pad / PPS)       dense / GQA
+  grid = (S,           n_pad / PPS)       MLA (latent cache is head-shared)
 
 with the per-slot block table and the per-row query positions passed as
 **scalar-prefetch** operands (``pltpu.PrefetchScalarGridSpec``): the k/v
 ``BlockSpec`` index maps read ``table[s, page]`` to pick the physical pool
 block each grid step fetches, so the data path never touches a dense
 gathered intermediate. The dense pools are stored ``[NB, BS, KV * D]``
-(the heads of one position side by side), so head ``h`` of a page is the
-``[BS, D]`` block at lane offset ``h * D`` — a tile-aligned fetch of
-exactly that head's bytes. Each grid step stages its PPS pages into a
-``[PPS * BS, D]`` K slab and computes one ``[ROWS, PPS * BS]`` score slab
-into a ``[ROWS, L]`` VMEM scratch (and stages the V pages into an
-``[L, Dv]`` scratch); at the last step of a slot it applies the shared
-Alg.-1 integer softmax (``core/alg1.py``) over the FULL rows and the
-weighted PV sum in the same residency.
+(the heads of one position side by side), so a block of HPP heads of a page
+is the ``[BS, HPP * D]`` block at lane offset ``hb * HPP * D``: at
+HPP = KV one contiguous fetch of the page's whole row, in place of KV
+strided ``[BS, D]`` head slices. Each grid step stages its PPS pages into
+a ``[PPS * BS, HPP * D]`` K slab and computes each head's ``[ROWS,
+PPS * BS]`` score slab from its own 128-lane-aligned ``D``-wide slice into
+rows ``i * ROWS ...`` of one ``[HPP * ROWS, L]`` VMEM scratch (and stages
+the V pages into an ``[L, HPP * Dv]`` scratch); at the last step of a slot
+it applies the shared Alg.-1 integer softmax (``core/alg1.py``) over the
+FULL rows of every head at once (rows are independent, so each row's codes
+are what a one-head program would give) and each head's weighted PV sum
+from its own lane slice of V, in the same residency. ``ops.choose_tiles``
+picks HPP and PPS from the shapes.
 
 TPU tiling: a score slab is stored at a dynamic lane offset, which Mosaic
 accepts only at a multiple of 128 lanes, so PPS * BS is a multiple of 128
 (``aligned_pages``) and the wrapper pads the block table with sentinel
 pages up to a whole number of steps. Padded columns lie past the real
-cache length and are masked out, so they add nothing to a row.
+cache length and are masked out, so they add nothing to a row. A head's
+slice of a multi-head page starts at a multiple of 128 lanes only when D
+and Dv are multiples of 128, so HPP > 1 needs both.
 
 DESIGN NOTE — why full rows, not online rescaling: flash-style softmax
 accumulates ``exp(x - m_running)`` and rescales the partial sums when the
@@ -64,13 +71,14 @@ only within a few bf16 ulps — ``chip_smoke.py`` measures the drift):
   * the int8 KV dequant (``kv_quant``) is fused into the page load:
     ``(codes.astype(f32) * scale).astype(compute)`` is elementwise, so
     dequantizing per page equals dequantizing the gathered whole. The
-    ``[BS, KV]`` scale page is fetched whole and head ``h``'s column is
-    selected by a masked lane sum, which is exact (one term, the rest 0).
+    ``[BS, KV]`` scale page is fetched whole, and each head's lane slice of
+    a page is dequantized with its own column, selected by a masked lane
+    sum, which is exact (one term, the rest 0).
 
 VMEM per program: ``launch/roofline.paged_tile_vmem_bytes``; ``ops``
-picks PPS against it and passes the same budget to the compiler as the
-kernel's VMEM limit, so a tile the model accepts is one the compiler
-accepts.
+picks HPP and PPS against it and passes the same budget to the compiler
+as the kernel's VMEM limit, so a tile the model accepts is one the
+compiler accepts.
 """
 
 from __future__ import annotations
@@ -120,14 +128,19 @@ def _rounded_dot(a, b, compute_dtype):
     return out.astype(compute_dtype)
 
 
-def _row_mask(pos_ref, s, group, window, shape, length):
-    """[ROWS, L] validity: row t*group+g attends kv positions <= pos[s, t]
-    (within the trailing window when set) and below the real cache
-    ``length`` (the padded tail never counts) — ``valid_upto``/
-    ``verify_mask`` semantics, shared by decode (T=1) and speculative
-    verify (T=K+1). Positions are read one scalar at a time: a TPU kernel
-    loads only scalars from SMEM, where the prefetched positions live."""
-    row_t = jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0) // group
+def _row_mask(pos_ref, s, group, window, shape, length, rows):
+    """[HPP * ROWS, L] validity: row i*ROWS + t*group + g attends kv
+    positions <= pos[s, t] (within the trailing window when set) and below
+    the real cache ``length`` (the padded tail never counts) —
+    ``valid_upto``/``verify_mask`` semantics, shared by decode (T=1) and
+    speculative verify (T=K+1). ``rows`` is one head's row count (MLA's
+    head-shared block: all of ``shape[0]``). Positions are read one scalar
+    at a time: a TPU kernel loads only scalars from SMEM, where the
+    prefetched positions live."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+    if rows != shape[0]:
+        row = row % rows
+    row_t = row // group
     qpos = jnp.zeros((shape[0], 1), jnp.int32)
     for t in range(pos_ref.shape[1]):
         qpos = jnp.where(row_t == t, pos_ref[s, t], qpos)
@@ -159,9 +172,25 @@ def _compiler_params(vmem_limit):
 # --------------------------------------------------------------- dense / GQA
 
 
+def _stage_page(dst, at, tile_ref, scale_ref, ent, nb, h0, hpp,
+                compute_dtype):
+    """Stage one fetched page of ``hpp`` heads into rows ``at`` of ``dst``.
+    An int8 page is dequantized head by head, each lane slice with its own
+    scale column (head ``h0 + i``)."""
+    if scale_ref is None:
+        dst[at, :] = _page_tile(tile_ref[0], ent, nb)
+        return
+    w = tile_ref.shape[-1] // hpp
+    for i in range(hpp):
+        cols = slice(i * w, (i + 1) * w)
+        dst[at, cols] = _page_tile(tile_ref[0, :, cols], ent, nb,
+                                   _head_column(scale_ref[0], h0 + i),
+                                   compute_dtype)
+
+
 def _dense_kernel(table_ref, pos_ref, q_ref, *refs, cfg: PrecisionConfig,
-                  scale: float, window: int, group: int, pps: int, bs: int,
-                  nb: int, length: int, quant: bool, compute_dtype,
+                  scale: float, window: int, group: int, hpp: int, pps: int,
+                  bs: int, nb: int, length: int, quant: bool, compute_dtype,
                   scores_dtype):
     k_refs = refs[:pps]
     v_refs = refs[pps:2 * pps]
@@ -169,34 +198,39 @@ def _dense_kernel(table_ref, pos_ref, q_ref, *refs, cfg: PrecisionConfig,
     vs_refs = refs[3 * pps:4 * pps] if quant else (None,) * pps
     nin = pps * (4 if quant else 2)
     o_ref, scores, v_scr, k_slab = refs[nin:nin + 4]
+    rows, d = q_ref.shape[2:]
+    dv = o_ref.shape[-1]
 
-    s, h, pp = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    s, hb, pp = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     for j in range(pps):
         page = pp * pps + j
         ent = table_ref[s, page]
-        ks = _head_column(ks_refs[j][0], h) if quant else None
-        vs = _head_column(vs_refs[j][0], h) if quant else None
-        k_slab[pl.ds(j * bs, bs), :] = _page_tile(k_refs[j][0], ent, nb, ks,
-                                                  compute_dtype)
-        v_scr[pl.ds(page * bs, bs), :] = _page_tile(v_refs[j][0], ent, nb,
-                                                    vs, compute_dtype)
+        _stage_page(k_slab, pl.ds(j * bs, bs), k_refs[j], ks_refs[j], ent,
+                    nb, hb * hpp, hpp, compute_dtype)
+        _stage_page(v_scr, pl.ds(page * bs, bs), v_refs[j], vs_refs[j], ent,
+                    nb, hb * hpp, hpp, compute_dtype)
     width = pps * bs
-    st = _rounded_dot(q_ref[0, 0], k_slab[...],
-                      compute_dtype).astype(scores_dtype) * scale
-    scores[:, _slab_cols(pp, width)] = st.astype(jnp.float32)
+    for i in range(hpp):
+        st = _rounded_dot(q_ref[0, i], k_slab[:, i * d:(i + 1) * d],
+                          compute_dtype).astype(scores_dtype) * scale
+        scores[i * rows:(i + 1) * rows, _slab_cols(pp, width)] = (
+            st.astype(jnp.float32))
 
     @pl.when(pp == pl.num_programs(2) - 1)
     def _():
-        mask = _row_mask(pos_ref, s, group, window, scores.shape, length)
+        mask = _row_mask(pos_ref, s, group, window, scores.shape, length,
+                         rows)
         probs = int_softmax_block(scores[...].astype(scores_dtype), mask, cfg)
-        o_ref[0, 0] = _pv(probs, v_scr[...], compute_dtype)
+        for i in range(hpp):
+            o_ref[0, i] = _pv(probs[i * rows:(i + 1) * rows],
+                              v_scr[:, i * dv:(i + 1) * dv], compute_dtype)
 
 
 def paged_attention_dense(q, k_pool, v_pool, table, positions,
                           cfg: PrecisionConfig, *, scale: float,
                           window: int = 0, k_scale=None, v_scale=None,
                           scores_dtype=jnp.float32, pps: int = 8,
-                          length=None, vmem_limit=None,
+                          hpp: int = 1, length=None, vmem_limit=None,
                           interpret: bool = True):
     """Fused paged decode attention, dense/GQA layout.
 
@@ -207,6 +241,8 @@ def paged_attention_dense(q, k_pool, v_pool, table, positions,
     table      [S, NLOG] int32    per-slot block table; sentinel = any
                                   entry outside [0, NB); NLOG % pps == 0
     positions  [S, T]  int32      per-query absolute positions
+    hpp        kv heads per program (divides KV); each page is fetched as
+               one [BS, hpp * D] block
     length     real cache length (default NLOG * BS); columns past it are
                padding and masked out
     -> [S, KV, ROWS, Dv] in the compute dtype (q's dtype).
@@ -219,6 +255,7 @@ def paged_attention_dense(q, k_pool, v_pool, table, positions,
     assert k_pool.shape[-1] == kv * d, (k_pool.shape, kv, d)
     assert rows % t == 0, (rows, t)
     assert nlog % pps == 0, (nlog, pps)
+    assert kv % hpp == 0, (kv, hpp)
     group = rows // t
     quant = k_scale is not None
     compute_dtype = q.dtype
@@ -229,19 +266,21 @@ def paged_attention_dense(q, k_pool, v_pool, table, positions,
     l_full = nlog * bs
 
     def kv_index(j):
-        def idx(s, h, pp, table_ref, pos_ref):
-            return (jnp.clip(table_ref[s, pp * pps + j], 0, nb - 1), 0, h)
+        def idx(s, hb, pp, table_ref, pos_ref):
+            return (jnp.clip(table_ref[s, pp * pps + j], 0, nb - 1), 0, hb)
         return idx
 
     def sc_index(j):
-        def idx(s, h, pp, table_ref, pos_ref):
+        def idx(s, hb, pp, table_ref, pos_ref):
             return (jnp.clip(table_ref[s, pp * pps + j], 0, nb - 1), 0, 0)
         return idx
 
-    in_specs = [pl.BlockSpec((1, 1, rows, d),
-                             lambda s, h, pp, *_: (s, h, 0, 0))]
-    in_specs += [pl.BlockSpec((1, bs, d), kv_index(j)) for j in range(pps)]
-    in_specs += [pl.BlockSpec((1, bs, dv), kv_index(j)) for j in range(pps)]
+    in_specs = [pl.BlockSpec((1, hpp, rows, d),
+                             lambda s, hb, pp, *_: (s, hb, 0, 0))]
+    in_specs += [pl.BlockSpec((1, bs, hpp * d), kv_index(j))
+                 for j in range(pps)]
+    in_specs += [pl.BlockSpec((1, bs, hpp * dv), kv_index(j))
+                 for j in range(pps)]
     operands = [q] + [k_pool] * pps + [v_pool] * pps
     if quant:
         in_specs += [pl.BlockSpec((1, bs, kv), sc_index(j))
@@ -250,16 +289,16 @@ def paged_attention_dense(q, k_pool, v_pool, table, positions,
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s_, kv, nlog // pps),
+        grid=(s_, kv // hpp, nlog // pps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, dv),
-                               lambda s, h, pp, *_: (s, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((rows, l_full), jnp.float32),
-                        pltpu.VMEM((l_full, dv), compute_dtype),
-                        pltpu.VMEM((pps * bs, d), compute_dtype)])
+        out_specs=pl.BlockSpec((1, hpp, rows, dv),
+                               lambda s, hb, pp, *_: (s, hb, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((hpp * rows, l_full), jnp.float32),
+                        pltpu.VMEM((l_full, hpp * dv), compute_dtype),
+                        pltpu.VMEM((pps * bs, hpp * d), compute_dtype)])
     kernel = functools.partial(
         _dense_kernel, cfg=cfg, scale=scale, window=window, group=group,
-        pps=pps, bs=bs, nb=nb, length=length, quant=quant,
+        hpp=hpp, pps=pps, bs=bs, nb=nb, length=length, quant=quant,
         compute_dtype=compute_dtype, scores_dtype=scores_dtype)
     return pl.pallas_call(
         kernel,
@@ -299,7 +338,8 @@ def _mla_kernel(table_ref, pos_ref, ql_ref, qr_ref, *refs,
 
     @pl.when(pp == pl.num_programs(1) - 1)
     def _():
-        mask = _row_mask(pos_ref, s, heads, 0, scores.shape, length)
+        mask = _row_mask(pos_ref, s, heads, 0, scores.shape, length,
+                         scores.shape[0])
         probs = int_softmax_block(scores[...], mask, cfg)
         o_ref[0] = _pv(probs, c_scr[...], compute_dtype)
 

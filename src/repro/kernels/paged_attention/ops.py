@@ -2,7 +2,8 @@
 
 These wrappers own everything the kernels keep out of their grids: the
 model-layout <-> kernel-layout reshapes (rows are ``t * group + g`` dense,
-``t * heads + h`` MLA), the pages-per-step choice (``choose_tiles``,
+``t * heads + h`` MLA), the tile choice (``choose_tiles``: kv heads per
+program and pages per step,
 validated against the roofline VMEM model, whose budget is also the
 kernel's compiler VMEM limit), the sentinel padding of the block table to a
 whole number of grid steps, and the interpret default
@@ -18,7 +19,7 @@ import jax.numpy as jnp
 from repro.core.precision import PrecisionConfig
 from repro.kernels import resolve_interpret
 from repro.kernels.paged_attention.kernel import (
-    aligned_pages, paged_attention_dense, paged_attention_mla,
+    LANES, aligned_pages, paged_attention_dense, paged_attention_mla,
 )
 from repro.launch.roofline import VMEM_LIMIT_BYTES, paged_tile_vmem_bytes
 
@@ -28,27 +29,37 @@ _STEP_MULTIPLES = (4, 2, 1)
 @functools.lru_cache(maxsize=None)
 def choose_tiles(rows: int, n_logical: int, block_size: int, d_head: int,
                  dv_head: int, compute_bytes: int = 2, quant: bool = False,
-                 vmem_budget: int = VMEM_LIMIT_BYTES) -> int:
-    """Pick pages-per-step for the paged kernel: a multiple of
+                 vmem_budget: int = VMEM_LIMIT_BYTES,
+                 kv_heads: int = 1) -> tuple[int, int]:
+    """Pick ``(pps, hpp)`` for the paged kernel: pages per grid step and kv
+    heads per program. ``hpp`` is the largest divisor of ``kv_heads`` (the
+    heads the kernel is handed; 1 for MLA) for which some step fits; a
+    block of several heads needs 128-lane-aligned head slices, so ``d_head``
+    and ``dv_head`` multiples of 128. ``pps`` is a multiple of
     ``aligned_pages(block_size)`` (each step's score slab fills whole
     128-lane tiles), the largest that divides the table once it is padded
-    to the next aligned length, and fits the roofline VMEM model
-    (``launch/roofline.paged_tile_vmem_bytes``) within ``vmem_budget`` —
+    to the next aligned length. A tile fits when the roofline VMEM model
+    (``launch/roofline.paged_tile_vmem_bytes``) is within ``vmem_budget`` —
     the limit the kernel is compiled with. Cached per static config — the
     choice is a trace-time constant, so it can never cause a retrace
     mid-serve. Fails loudly (instead of letting the compiler refuse the
-    kernel) when even the smallest step exceeds the budget."""
+    kernel) when even one head at the smallest step exceeds the budget."""
     base = aligned_pages(block_size)
     n_pad = -(-n_logical // base) * base
     l_full = n_pad * block_size
-    for m in _STEP_MULTIPLES:
-        pps = base * m
-        if n_pad % pps != 0:
+    lane_aligned = d_head % LANES == 0 and dv_head % LANES == 0
+    for hpp in range(kv_heads if lane_aligned else 1, 0, -1):
+        if kv_heads % hpp != 0:
             continue
-        need = paged_tile_vmem_bytes(rows, l_full, block_size, d_head,
-                                     dv_head, pps, compute_bytes, quant)
-        if need <= vmem_budget:
-            return pps
+        for m in _STEP_MULTIPLES:
+            pps = base * m
+            if n_pad % pps != 0:
+                continue
+            need = paged_tile_vmem_bytes(rows, l_full, block_size, d_head,
+                                         dv_head, pps, compute_bytes, quant,
+                                         hpp)
+            if need <= vmem_budget:
+                return pps, hpp
     need = paged_tile_vmem_bytes(rows, l_full, block_size, d_head, dv_head,
                                  base, compute_bytes, quant)
     raise ValueError(
@@ -83,13 +94,14 @@ def paged_attend_dense(q, k_pool, v_pool, table, positions,
     quant = k_scale is not None
     qk = q.reshape(b, t, kvh, group, d).transpose(0, 2, 1, 3, 4)
     qk = qk.reshape(b, kvh, rows, d)
-    pps = choose_tiles(rows, table.shape[1], bs, d, dv,
-                       jnp.dtype(q.dtype).itemsize, quant)
+    pps, hpp = choose_tiles(rows, table.shape[1], bs, d, dv,
+                            jnp.dtype(q.dtype).itemsize, quant,
+                            kv_heads=kvh)
     out = paged_attention_dense(
         qk, k_pool, v_pool, _pad_table(table, pps, nb),
         positions.astype(jnp.int32), pcfg,
         scale=scale, window=window, k_scale=k_scale, v_scale=v_scale,
-        scores_dtype=jnp.dtype(scores_dtype), pps=pps,
+        scores_dtype=jnp.dtype(scores_dtype), pps=pps, hpp=hpp,
         length=table.shape[1] * bs, vmem_limit=VMEM_LIMIT_BYTES,
         interpret=resolve_interpret(interpret))
     out = out.reshape(b, kvh, t, group, dv).transpose(0, 2, 1, 3, 4)
@@ -107,8 +119,8 @@ def paged_attend_mla(q_lat, q_rope, c_pool, kr_pool, table, positions,
     nb, bs = c_pool.shape[:2]
     rows = t * h
     # dv slot = R (the [L, R] latent scratch dominates, mirroring dense's V)
-    pps = choose_tiles(rows, table.shape[1], bs, dr, r,
-                       jnp.dtype(q_lat.dtype).itemsize, False)
+    pps, _ = choose_tiles(rows, table.shape[1], bs, dr, r,
+                          jnp.dtype(q_lat.dtype).itemsize, False)
     out = paged_attention_mla(
         q_lat.reshape(b, rows, r), q_rope.reshape(b, rows, dr),
         c_pool, kr_pool, _pad_table(table, pps, nb),

@@ -130,21 +130,24 @@ def _tile_bytes(rows: int, cols: int, itemsize: int) -> int:
 
 def paged_tile_vmem_bytes(rows: int, l_full: int, block_size: int,
                           d_head: int, dv_head: int, pps: int,
-                          compute_bytes: int = 2, quant: bool = False) -> int:
-    """VMEM per (slot, head) program of the paged-decode kernel, counted
-    the way Mosaic lays it out (every array padded to native tiles).
+                          compute_bytes: int = 2, quant: bool = False,
+                          hpp: int = 1) -> int:
+    """VMEM per program of the paged-decode kernel (one slot and a block of
+    ``hpp`` kv heads; MLA is head-shared, ``hpp`` 1), counted the way Mosaic
+    lays it out (every array padded to native tiles).
 
-    scores scratch  [rows, l_full] f32           (full rows — no online
+    scores scratch  [hpp * rows, l_full] f32     (full rows — no online
                                                   rescaling, see kernel docs)
-    V scratch       [l_full, dv_head] compute dtype
-    K slab          [pps * block_size, d_head + dv_head] compute dtype
-                    (dense stages [.., d_head]; MLA stages both latent and
-                    rope slabs, so count both widths)
-    page tiles      2 buffers x pps x ([BS, d_head] + [BS, dv_head]) at the
-                    pool dtype (+ two [BS, KV] f32 scale pages when quant)
-    q / out blocks  2 buffers x [rows, d_head] / [rows, dv_head]
+    V scratch       [l_full, hpp * dv_head] compute dtype
+    K slab          [pps * block_size, hpp * (d_head + dv_head)] compute
+                    dtype (dense stages [.., hpp * d_head]; MLA stages both
+                    latent and rope slabs, so count both widths)
+    page tiles      2 buffers x pps x ([BS, hpp * d_head] +
+                    [BS, hpp * dv_head]) at the pool dtype, one fetch each
+                    (+ two [BS, KV] f32 scale pages when quant)
+    q / out blocks  2 buffers x hpp x [rows, d_head] / [rows, dv_head]
     softmax         the Alg.-1 body's live full-row temporaries at the last
-                    step: five 32-bit [rows, l_full] arrays and the
+                    step: five 32-bit [hpp * rows, l_full] arrays and the
                     compute-dtype probabilities
 
     Checked against the v5e compiler (``tests/test_tpu_compile.py``): an
@@ -152,18 +155,19 @@ def paged_tile_vmem_bytes(rows: int, l_full: int, block_size: int,
     the shapes serving uses (1 to 64 rows, 4k to 32k columns).
     """
     elt = 1 if quant else compute_bytes
-    scratch = (_tile_bytes(rows, l_full, 4)
-               + _tile_bytes(l_full, dv_head, compute_bytes)
-               + _tile_bytes(pps * block_size, d_head, compute_bytes)
-               + _tile_bytes(pps * block_size, dv_head, compute_bytes))
-    tiles = pps * (_tile_bytes(block_size, d_head, elt)
-                   + _tile_bytes(block_size, dv_head, elt))
+    w, wv = hpp * d_head, hpp * dv_head
+    scratch = (_tile_bytes(hpp * rows, l_full, 4)
+               + _tile_bytes(l_full, wv, compute_bytes)
+               + _tile_bytes(pps * block_size, w, compute_bytes)
+               + _tile_bytes(pps * block_size, wv, compute_bytes))
+    tiles = pps * (_tile_bytes(block_size, w, elt)
+                   + _tile_bytes(block_size, wv, elt))
     if quant:
         tiles += 2 * pps * _tile_bytes(block_size, 1, 4)
-    blocks = (_tile_bytes(rows, d_head, compute_bytes)
-              + _tile_bytes(rows, dv_head, compute_bytes))
-    softmax = (5 * _tile_bytes(rows, l_full, 4)
-               + _tile_bytes(rows, l_full, compute_bytes))
+    blocks = hpp * (_tile_bytes(rows, d_head, compute_bytes)
+                    + _tile_bytes(rows, dv_head, compute_bytes))
+    softmax = (5 * _tile_bytes(hpp * rows, l_full, 4)
+               + _tile_bytes(hpp * rows, l_full, compute_bytes))
     return scratch + 2 * (tiles + blocks) + softmax
 
 

@@ -116,8 +116,9 @@ def cache_zeros(cfg, batch: int, cache_len: int, enc_len: int = 0):
 
 def _paged_attn_cache(make, L, nb, bs, slots, n_logical, cfg):
     # K/V pools keep one position's heads side by side ([..., KV * D]), so
-    # the fused paged-decode kernel fetches head h of a page as one
-    # tile-aligned [BS, D] block (see kernels/paged_attention/kernel.py)
+    # the fused paged-decode kernel fetches a block of hpp heads of a page
+    # as one [BS, hpp * D] block — the page's whole contiguous row when
+    # hpp = KV (see kernels/paged_attention/kernel.py)
     row = cfg.n_kv_heads * cfg.d_head
     if getattr(cfg, "kv_quant", False):
         d = {"k": make((L, nb, bs, row), jnp.int8),

@@ -21,6 +21,7 @@ Engine-level parity (serve tokens, speculative composition) lives in
 """
 
 import dataclasses
+import functools
 
 import numpy as np
 import jax
@@ -33,6 +34,7 @@ from repro.core.precision import BEST
 from repro.core.softmax_variants import SoftmaxSpec
 from repro.kernels.paged_attention import ops
 from repro.kernels.paged_attention.kernel import aligned_pages
+from repro.launch.roofline import paged_tile_vmem_bytes
 from repro.models import build_model, kv_cache
 from repro.models.attention import paged_gather
 
@@ -113,18 +115,10 @@ def _mixed_table(rng, B, NLOG, NB, BS, T):
 # --------------------------------------------------------- kernel-level
 
 
-@pytest.mark.parametrize("bs,nlog", [(8, 4), (16, 4), (64, 2)])
-@pytest.mark.parametrize("t,kvh,window,quant", [
-    (1, 2, 0, False),    # decode, MHA-ish
-    (1, 1, 0, False),    # decode, GQA group=4
-    (3, 2, 0, False),    # verify rows
-    (1, 2, 12, False),   # sliding window
-    (1, 2, 0, True),     # int8 pools, fused dequant
-])
-def test_dense_kernel_bitexact(bs, nlog, t, kvh, window, quant):
-    B, H, D = 3, 4, 32
+def _check_dense(bs, nlog, t, kvh, window, quant, *, H, D, seed):
+    B = 3
     NB = B * nlog + 2
-    r = np.random.default_rng(hash((bs, nlog, t, kvh, window, quant)) % 2**31)
+    r = np.random.default_rng(seed)
     q = jnp.asarray(r.normal(size=(B, t, H, D)), jnp.bfloat16)
     if quant:
         k_pool = jnp.asarray(r.integers(-127, 128, (NB, bs, kvh, D)), jnp.int8)
@@ -145,6 +139,47 @@ def test_dense_kernel_bitexact(bs, nlog, t, kvh, window, quant):
                                  v_scale=v_scale, interpret=True)
     assert jnp.array_equal(want.astype(jnp.float32),
                            got.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("bs,nlog", [(8, 4), (16, 4), (64, 2)])
+@pytest.mark.parametrize("t,kvh,window,quant", [
+    (1, 2, 0, False),    # decode, MHA-ish
+    (1, 1, 0, False),    # decode, GQA group=4
+    (3, 2, 0, False),    # verify rows
+    (1, 2, 12, False),   # sliding window
+    (1, 2, 0, True),     # int8 pools, fused dequant
+])
+def test_dense_kernel_bitexact(bs, nlog, t, kvh, window, quant):
+    _check_dense(bs, nlog, t, kvh, window, quant, H=4, D=32,
+                 seed=hash((bs, nlog, t, kvh, window, quant)) % 2**31)
+
+
+@pytest.mark.parametrize("hpp", [1, 2, 4])
+@pytest.mark.parametrize("t,window,quant", [
+    (1, 0, False),       # decode
+    (3, 0, False),       # verify rows
+    (1, 12, False),      # sliding window
+    (1, 0, True),        # int8 pools, each head dequantized with its scale
+], ids=["decode", "verify", "window", "int8"])
+def test_dense_kernel_bitexact_head_blocks(monkeypatch, hpp, t, window,
+                                           quant):
+    """Programs over a block of ``hpp`` of the 4 kv heads (GQA group 2, 128
+    lanes per head): every row of the shared [hpp * ROWS, L] score block
+    still matches the reference bit for bit. A smaller ``hpp`` is forced
+    through a VMEM budget that fits it at the smallest step and no more."""
+    bs, nlog, kvh, d = 16, 4, 4, 128
+    rows = t * 2
+    base = aligned_pages(bs)
+    if hpp < kvh:
+        l_full = -(-nlog // base) * base * bs
+        budget = paged_tile_vmem_bytes(rows, l_full, bs, d, d, base, 2,
+                                       quant, hpp)
+        monkeypatch.setattr(ops, "choose_tiles", functools.partial(
+            ops.choose_tiles, vmem_budget=budget))
+    assert ops.choose_tiles(rows, nlog, bs, d, d, 2, quant,
+                            kv_heads=kvh)[1] == hpp
+    _check_dense(bs, nlog, t, kvh, window, quant, H=8, D=d,
+                 seed=hash((hpp, t, window, quant)) % 2**31)
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -244,16 +279,51 @@ def test_interpret_only_on_cpu(monkeypatch):
 
 def test_choose_tiles_divides_and_fits():
     """pps * block_size fills whole 128-lane tiles (the score slab's store
-    offset must be lane-aligned on the chip) and divides the table."""
+    offset must be lane-aligned on the chip) and divides the table; hpp
+    divides the kv heads, and exceeds 1 only with 128-lane head slices."""
     for bs, nlog in [(16, 256), (8, 64), (64, 20), (256, 3)]:
-        pps = ops.choose_tiles(4, nlog, bs, 64, 64, 2, False)
-        base = aligned_pages(bs)
-        assert pps * bs % 128 == 0 and pps % base == 0
-        assert (-(-nlog // base) * base) % pps == 0
+        for d, kv in [(64, 1), (64, 4), (128, 4), (128, 16), (128, 6)]:
+            pps, hpp = ops.choose_tiles(4, nlog, bs, d, d, 2, False,
+                                        kv_heads=kv)
+            base = aligned_pages(bs)
+            assert pps * bs % 128 == 0 and pps % base == 0
+            assert (-(-nlog // base) * base) % pps == 0
+            assert kv % hpp == 0 and (hpp == 1 or d % 128 == 0)
+            l_full = -(-nlog // base) * base * bs
+            assert paged_tile_vmem_bytes(4, l_full, bs, d, d, pps, 2, False,
+                                         hpp) <= ops.VMEM_LIMIT_BYTES
     # a table that is not a whole number of aligned steps is padded to the
     # next one, and the step then divides the padded length
-    assert ops.choose_tiles(4, 12, 16, 64, 64, 2, False) == 16
-    assert ops.choose_tiles(4, 34, 16, 64, 64, 2, False) == 8
+    assert ops.choose_tiles(4, 12, 16, 64, 64, 2, False) == (16, 1)
+    assert ops.choose_tiles(4, 34, 16, 64, 64, 2, False) == (8, 1)
+
+
+@pytest.mark.parametrize("n_logical,pps", [(144, 16), (212, 8)],
+                         ids=["chat", "doc-qa"])
+def test_choose_tiles_all_heads_at_olmo_1b_cells(n_logical, pps):
+    """olmo-1b's benchmark geometries (one decode row per head, 16 kv heads
+    of 128): every head in one program, so each page is one whole-row
+    fetch."""
+    assert ops.choose_tiles(1, n_logical, 16, 128, 128, 2, False,
+                            kv_heads=16) == (pps, 16)
+
+
+@pytest.mark.parametrize("quant", [False, True])
+def test_choose_tiles_fewer_heads_at_32k(quant):
+    """A 32k olmo-1b table cannot hold all 16 heads' V rows in VMEM (128
+    MiB): the choice falls back to a smaller divisor that fits."""
+    pps, hpp = ops.choose_tiles(1, 2048, 16, 128, 128, 2, quant,
+                                kv_heads=16)
+    assert 1 < hpp < 16 and 16 % hpp == 0
+    assert paged_tile_vmem_bytes(1, 2048 * 16, 16, 128, 128,
+                                 aligned_pages(16), 2, quant,
+                                 2 * hpp) > ops.VMEM_LIMIT_BYTES
+
+
+def test_choose_tiles_mla_unchanged_at_minicpm3_4b():
+    """The head-shared MLA kernel keeps its step: 40 rows over 276 pages of
+    the 256-wide latent and 32 rope dims, 8 pages per step, one program."""
+    assert ops.choose_tiles(40, 276, 16, 32, 256, 2, False) == (8, 1)
 
 
 def test_choose_tiles_rejects_loudly():

@@ -109,22 +109,17 @@ def test_int_softmax_kernel_compiles_at_attn_chunk(one_chip, masked):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_choose_tiles_matches_compiler_vmem_limit(one_chip):
-    """``choose_tiles`` and the compiler agree at the VMEM boundary: the
-    longest table ``choose_tiles`` accepts within a budget compiles with that
-    budget as the kernel's VMEM limit, and one 30% longer is refused by both.
-    (The roofline model is an upper bound within 25% of what Mosaic
-    allocates, so an accepted tile always compiles.) The budget is Mosaic's
-    default 16 MiB scope: at serving's ``VMEM_LIMIT_BYTES`` the same check
-    holds but compiles for over two minutes (Mosaic unrolls the full-row
-    softmax, so compile time grows with the VMEM the rows fill)."""
-    rows, d, budget = 64, 128, 16 * 2 ** 20
+def _check_vmem_boundary(sharding, hpp):
+    """The longest table for which ``choose_tiles`` still gives a program
+    ``hpp`` kv heads within a 16 MiB budget compiles with that budget as
+    the kernel's VMEM limit; one 30% longer is refused by both."""
+    rows, d, budget = 64 // hpp, 128, 16 * 2 ** 20
     base = paged_kernel.aligned_pages(BS)
 
     def accepts(nlog):
         try:
-            ops.choose_tiles(rows, nlog, BS, d, d, 2, False, budget)
-            return True
+            return ops.choose_tiles(rows, nlog, BS, d, d, 2, False, budget,
+                                    hpp)[1] == hpp
         except ValueError:
             return False
 
@@ -139,17 +134,35 @@ def test_choose_tiles_matches_compiler_vmem_limit(one_chip):
 
         def fn(q, k, v, table, pos):
             return paged_kernel.paged_attention_dense(
-                q, k, v, table, pos, CFG, scale=0.1, pps=pps,
+                q, k, v, table, pos, CFG, scale=0.1, pps=pps, hpp=hpp,
                 vmem_limit=budget, interpret=False)
 
-        return _compile(fn, [((1, 1, rows, d), jnp.bfloat16),
-                             ((nlog, BS, d), jnp.bfloat16),
-                             ((nlog, BS, d), jnp.bfloat16),
+        return _compile(fn, [((1, hpp, rows, d), jnp.bfloat16),
+                             ((nlog, BS, hpp * d), jnp.bfloat16),
+                             ((nlog, BS, hpp * d), jnp.bfloat16),
                              ((1, nlog), jnp.int32), ((1, rows), jnp.int32)],
-                        one_chip)
+                        sharding)
 
     assert "tpu_custom_call" in compile_at(lo).as_text()
     over = -(-int(lo * 1.3) // base) * base
     assert not accepts(over)
     with pytest.raises(Exception, match="(?i)vmem|memory"):
         compile_at(over)
+
+
+def test_choose_tiles_matches_compiler_vmem_limit(one_chip):
+    """``choose_tiles`` and the compiler agree at the VMEM boundary, one kv
+    head per program (64 rows). (The roofline model is an upper bound
+    within 25% of what Mosaic allocates, so an accepted tile always
+    compiles.) The budget is Mosaic's default 16 MiB scope: at serving's
+    ``VMEM_LIMIT_BYTES`` the same check holds but compiles for over two
+    minutes (Mosaic unrolls the full-row softmax, so compile time grows
+    with the VMEM the rows fill)."""
+    _check_vmem_boundary(one_chip, 1)
+
+
+def test_choose_tiles_matches_compiler_vmem_limit_head_block(one_chip):
+    """The same boundary for a program over a block of 4 kv heads (16 rows
+    each, the same 64 score rows): each page is one [BS, 4 * 128] fetch and
+    V is staged [L, 4 * 128]."""
+    _check_vmem_boundary(one_chip, 4)
